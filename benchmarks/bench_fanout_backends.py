@@ -1,4 +1,4 @@
-"""Fanout backend scaling: serial vs threads vs device at 4 shards.
+"""Fanout backend scaling: serial vs threads vs device.
 
 The ``fanout`` optimizer runs n independent seeds of an inner search and
 merges the best -- the paper's sample-efficiency claim evaluated as a
@@ -9,67 +9,56 @@ ga):
   * serial  -- n compiles + n sequential executions (the PR-1 baseline)
   * threads -- n compiles + n executions, overlapped by host threads
   * device  -- ONE compile of a shard_map'd program + all shards executing
-               concurrently on the forced-host CPU devices
+               concurrently, one shard per local device
 
 All backends produce bit-identical merged outcomes (asserted), so the only
-difference is time.  Subprocesses own the XLA device-count flag, exactly
-like bench_dist_search.
+difference is time.  On an accelerator the shards run in this process over
+the real local devices (4 on the CPU rehearsal, which forces host devices
+in a child process -- see ``common.run_on_devices``).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import time
 
 from benchmarks import common
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_CODE = """
-import json, time
-from repro import api
-from repro.costmodel import workloads
+def measure(inner: str, eps: int, shards: int, inner_opts: dict) -> dict:
+    """Time each backend on one fanout request; asserts identical merges."""
+    from repro import api
+    from repro.costmodel import workloads
 
-wl = workloads.mobilenet_v2()[:12]
-req = dict(workload=wl, env=api.EnvConfig(platform="iot"),
-           eps={eps}, seed=0, method="fanout")
-res = {{}}
-for backend in ("serial", "threads", "device"):
-    t0 = time.time()
-    out = api.run_search(api.SearchRequest(
-        **req, options={{"inner": "{inner}", "n_shards": {shards},
-                         "backend": backend,
-                         "inner_options": {inner_opts}}}))
-    res[backend] = {{"seconds": time.time() - t0,
-                     "best_value": out.best_value,
-                     "history_tail": float(out.history[-1])}}
-    assert out.extras["backend"] == backend
-# All three must merge to the same ensemble result.
-assert len({{r["best_value"] for r in res.values()}}) == 1, res
-print(json.dumps(res))
-"""
-
-
-def _run(inner, eps, shards, inner_opts):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={shards}"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    code = _CODE.format(inner=inner, eps=eps, shards=shards,
-                        inner_opts=json.dumps(inner_opts))
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, timeout=1800, env=env)
-    assert out.returncode == 0, out.stderr[-2000:]
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    req = dict(workload=workloads.mobilenet_v2()[:12],
+               env=api.EnvConfig(platform="iot"), eps=eps, seed=0,
+               method="fanout")
+    res = {}
+    for backend in ("serial", "threads", "device"):
+        t0 = time.time()
+        out = api.run_search(api.SearchRequest(
+            **req, options={"inner": inner, "n_shards": shards,
+                            "backend": backend, "inner_options": inner_opts}))
+        res[backend] = {"seconds": time.time() - t0,
+                        "best_value": out.best_value,
+                        "history_tail": float(out.history[-1])}
+        assert out.extras["backend"] == backend
+    # All three must merge to the same ensemble result.
+    assert len({r["best_value"] for r in res.values()}) == 1, res
+    return res
 
 
 def run(budget_name: str = "quick") -> dict:
+    import jax
+
     eps = 300 if budget_name == "quick" else 2000
-    shards = 4
-    payload = {"n_shards": shards, "eps": eps}
+    shards = (4 if jax.default_backend() == "cpu"
+              else min(4, len(jax.devices())))
+    payload = {"n_shards": shards, "eps": eps,
+               "platform": jax.default_backend()}
     rows = []
     for inner, iopts in [("reinforce", {}), ("ga", {"population": 50})]:
-        r = _run(inner, eps, shards, iopts)
+        r = common.run_on_devices(
+            "benchmarks.bench_fanout_backends", "measure", shards,
+            inner=inner, eps=eps, shards=shards, inner_opts=iopts)
         payload[inner] = r
         base = r["serial"]["seconds"]
         for backend in ("serial", "threads", "device"):

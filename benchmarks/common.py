@@ -7,13 +7,16 @@ Eps=5000).  Results are printed as aligned tables and written to
 """
 from __future__ import annotations
 
+import importlib
 import json
 import os
+import subprocess
+import sys
 import time
 from typing import Dict, List, Sequence
 
-RESULTS_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "results")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RESULTS_DIR = os.path.join(REPO, "results")
 
 # Sample budgets (paper: Eps = 5000).
 BUDGETS = {
@@ -66,6 +69,37 @@ def _jsonable(o):
     if isinstance(o, np.ndarray):
         return o.tolist()
     return str(o)
+
+
+def run_on_devices(module: str, func: str, n_devices: int, /,
+                   timeout: float = 1800, **kwargs):
+    """``module.func(**kwargs)`` where ``n_devices`` devices exist; returns
+    its JSON-able result.
+
+    On an accelerator the call runs in this process over the real local
+    devices: this process already holds the chip, so a child could not get
+    it.  On the CPU it runs in a child started with
+    ``--xla_force_host_platform_device_count=n_devices`` (the flag must
+    precede JAX start-up) -- a rehearsal of the multi-device path, not a device
+    measurement.
+    """
+    import jax
+
+    if jax.default_backend() != "cpu":
+        if len(jax.devices()) < n_devices:
+            raise RuntimeError(f"{module}.{func} needs {n_devices} devices, "
+                               f"this host has {len(jax.devices())}")
+        return getattr(importlib.import_module(module), func)(**kwargs)
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={n_devices}")
+    env["PYTHONPATH"] = os.pathsep.join([REPO, os.path.join(REPO, "src")])
+    code = (f"import json\nfrom {module} import {func}\n"
+            f"print(json.dumps({func}(**json.loads({json.dumps(kwargs)!r}))))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=timeout, env=env, cwd=REPO)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 class Timer:

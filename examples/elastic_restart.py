@@ -25,6 +25,7 @@ import numpy as np  # noqa: E402
 
 from repro import configs  # noqa: E402
 from repro.distributed import sharding  # noqa: E402
+from repro.launch.mesh import auto_mesh  # noqa: E402
 from repro.models import lm  # noqa: E402
 from repro.training import checkpoint, data, optim  # noqa: E402
 
@@ -33,7 +34,7 @@ STEPS = (20, 30, 40)   # checkpoint boundaries: mesh changes at each
 
 
 def train_segment(mesh_shape, start, stop, dcfg, cfg, opt, resume):
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = auto_mesh(mesh_shape, ("data", "model"))
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     opt_state = opt.init(params)
     psh = sharding.tree_shardings(mesh, params)

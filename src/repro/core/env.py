@@ -144,6 +144,24 @@ def select_objective(total_lat, total_en, cfg: EnvConfig):
     return total_lat ** w * total_en ** (jnp.float32(1.0) - w)
 
 
+def sum_layers(x):
+    """Sum over the last (layer) axis in one fixed pairwise order.
+
+    ``jnp.sum`` leaves the f32 association order to the compiler, which
+    picks it per program: the GA's in-graph fitness, a standalone
+    aggregation program and a reduction fused after a kernel can then
+    differ by an ulp for the same per-layer values.  Explicit adds of
+    contiguous halves are never reassociated, so every program that
+    aggregates the same values gets the same bits.
+    """
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        head = x[..., :h] + x[..., h:2 * h]
+        x = (head if x.shape[-1] % 2 == 0
+             else jnp.concatenate([head, x[..., 2 * h:]], axis=-1))
+    return x[..., 0]
+
+
 def aggregate_costs_multi(lat, en, area, pw, cfg: EnvConfig, budget):
     """Per-layer costs (..., N) -> whole-model
     (total_lat, total_en, total_area, total_pw, feasible).
@@ -156,11 +174,11 @@ def aggregate_costs_multi(lat, en, area, pw, cfg: EnvConfig, budget):
     so none of them can drift apart.  ``aggregate_costs`` below is the
     scalar-objective view of this same definition.
     """
-    total_lat = jnp.sum(lat, axis=-1)
-    total_en = jnp.sum(en, axis=-1)
+    total_lat = sum_layers(lat)
+    total_en = sum_layers(en)
     if cfg.scenario == "LP":
-        total_area = jnp.sum(area, axis=-1)
-        total_pw = jnp.sum(pw, axis=-1)
+        total_area = sum_layers(area)
+        total_pw = sum_layers(pw)
     else:
         total_area = jnp.max(area, axis=-1)
         total_pw = jnp.max(pw, axis=-1)

@@ -42,7 +42,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.api import registry as api_registry
@@ -52,6 +51,7 @@ from repro.core import env as env_lib
 from repro.core import ga as ga_lib
 from repro.core import policy as policy_lib
 from repro.core import reinforce
+from repro.launch.mesh import auto_mesh
 from repro.training import optim
 
 
@@ -195,11 +195,11 @@ def make_distributed_epoch(ecfg: env_lib.EnvConfig,
         return new_state, metrics
 
     rep = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         epoch_shard, mesh=mesh,
         in_specs=(rep, P(axes)),   # alive: one flag per device
         out_specs=(rep, rep),
-        check_rep=False)
+        check_vma=False)
     return fn
 
 
@@ -290,7 +290,7 @@ class _MergedProgress:
 
 
 def _shard_mesh(n_shards: int):
-    return jax.make_mesh((n_shards,), ("shard",))
+    return auto_mesh((n_shards,), ("shard",))
 
 
 def _stack_trees(trees):
@@ -333,8 +333,8 @@ def _fanout_reinforce_device(subs) -> list:
             return (jax.tree.map(lambda x: x[None], state2),
                     jax.tree.map(lambda x: x[None], metrics))
 
-        return shard_map(body, mesh=mesh, in_specs=(P_s,),
-                         out_specs=(P_s, P_s), check_rep=False)(stacked)
+        return jax.shard_map(body, mesh=mesh, in_specs=(P_s,),
+                             out_specs=(P_s, P_s), check_vma=False)(stacked)
 
     streaming = req0.on_progress is not None
     # Not streaming -> nothing happens between chunks, so run the whole
@@ -408,8 +408,8 @@ def _fanout_ga_device(subs) -> list:
                                         length=gens)
             return jax.tree.map(lambda x: x[None], carry2), hist[None]
 
-        return shard_map(body, mesh=mesh, in_specs=(P_s,),
-                         out_specs=(P_s, P_s), check_rep=False)(stacked)
+        return jax.shard_map(body, mesh=mesh, in_specs=(P_s,),
+                             out_specs=(P_s, P_s), check_vma=False)(stacked)
 
     t0 = time.time()
     final, hist = run_all(stacked)
@@ -557,7 +557,7 @@ class DistributedReinforceOptimizer:
         opts = request.options
         mesh = opts.get("mesh")
         if mesh is None:
-            mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+            mesh = auto_mesh((len(jax.devices()),), ("data",))
         n_dev = int(np.prod(list(mesh.shape.values())))
         E = int(opts.get("episodes_per_device", 1))
         per_epoch = max(E * n_dev, 1)

@@ -40,7 +40,6 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -157,14 +156,14 @@ def make_pp_train_step(cfg, optimizer, mesh, *, n_micro: int):
     def train_step(params: Dict[str, Any], opt_state, batch):
         blocks, embed = params["blocks"], params["embed"]
         ob, oe = opt_state
-        fn = shard_map(
+        fn = jax.shard_map(
             spmd_step, mesh=mesh,
             in_specs=(_specs(blocks, stage), _specs(embed, rep),
                       _specs(ob, stage), _specs(oe, rep),
                       dspec, dspec),
             out_specs=(_specs(blocks, stage), _specs(embed, rep),
                        _specs(ob, stage), _specs(oe, rep), rep),
-            check_rep=False)
+            check_vma=False)
         nb, ne, ob, oe, loss = fn(blocks, embed, ob, oe,
                                   batch["tokens"], batch["labels"])
         return {"blocks": nb, "embed": ne}, (ob, oe), loss

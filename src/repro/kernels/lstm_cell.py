@@ -38,10 +38,11 @@ def _sig(x):
 
 def _lstm_kernel(x_ref, h_ref, c_ref, wx_ref, wh_ref, b_ref,
                  h_out_ref, c_out_ref):
-    gates = (jnp.dot(x_ref[...], wx_ref[...],
-                     preferred_element_type=jnp.float32)
-             + jnp.dot(h_ref[...], wh_ref[...],
-                       preferred_element_type=jnp.float32)
+    # Full f32 matmuls: the policy is an f32 network, and the TPU's default
+    # matmul precision rounds the operands to bf16.
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    gates = (dot(x_ref[...], wx_ref[...]) + dot(h_ref[...], wh_ref[...])
              + b_ref[...])
     H = h_ref.shape[-1]
     i = _sig(gates[:, 0 * H:1 * H])

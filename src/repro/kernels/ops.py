@@ -96,9 +96,23 @@ def batched_cost_multi(layers, pe, kt, df, *, use_kernel: bool = True):
 
 
 def lstm_step(x, h, c, wx, wh, b, *, use_kernel: bool = True):
-    """One LSTM cell step.  x: (B, I); h/c: (B, H); returns (h', c')."""
+    """One LSTM cell step.  x: (B, I); h/c: (B, H); returns (h', c').
+
+    The kernel path is differentiable: its backward pass is the oracle's
+    (a Mosaic kernel has no autodiff rule, and REINFORCE differentiates
+    the policy step).
+    """
     if not use_kernel:
-        return ref.lstm_cell_ref(x, h, c, wx, wh, jnp.reshape(b, (-1,)))
+        return _lstm_ref(x, h, c, wx, wh, b)
+    return _lstm_kernel(x, h, c, wx, wh, b)
+
+
+def _lstm_ref(x, h, c, wx, wh, b):
+    return ref.lstm_cell_ref(x, h, c, wx, wh, jnp.reshape(b, (-1,)))
+
+
+@jax.custom_vjp
+def _lstm_kernel(x, h, c, wx, wh, b):
     B, I = x.shape
     H = h.shape[-1]
     # Pad the observation dim to the lane width and B to the batch tile.
@@ -112,6 +126,17 @@ def lstm_step(x, h, c, wx, wh, b, *, use_kernel: bool = True):
         x_p, h_p, c_p, wx_p, jnp.asarray(wh, jnp.float32), b2,
         interpret=_interpret())
     return h_new[:B], c_new[:B]
+
+
+def _lstm_kernel_fwd(*args):
+    return _lstm_kernel(*args), args
+
+
+def _lstm_kernel_bwd(args, g):
+    return jax.vjp(_lstm_ref, *args)[1](g)
+
+
+_lstm_kernel.defvjp(_lstm_kernel_fwd, _lstm_kernel_bwd)
 
 
 def decode_attention(q, k, v, *, use_kernel: bool = True):

@@ -5,6 +5,7 @@ over shape/dtype sweeps in tests/test_kernels.py).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.costmodel import maestro
@@ -41,7 +42,8 @@ def lstm_cell_ref(x, h, c, wx, wh, b):
     x: (B, I), h/c: (B, H), wx: (I, 4H), wh: (H, 4H), b: (4H,).
     Gate order: i, f, g, o.  Returns (h', c').
     """
-    gates = x @ wx + h @ wh + b
+    hi = jax.lax.Precision.HIGHEST     # f32 math on every backend
+    gates = jnp.dot(x, wx, precision=hi) + jnp.dot(h, wh, precision=hi) + b
     H = h.shape[-1]
     i = _sig(gates[..., 0 * H:1 * H])
     f = _sig(gates[..., 1 * H:2 * H])

@@ -8,6 +8,21 @@ everything else sees the real single CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis of type ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which indexing a
+    sharded array and ``with_sharding_constraint`` raise
+    ``ShardingTypeError``.  Every mesh in this repo feeds ``shard_map``
+    bodies and GSPMD sharding rules written for ``Auto`` axes.
+    """
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,12 +35,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
     """Tiny mesh over however many (host) devices exist -- for tests."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

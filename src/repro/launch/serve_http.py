@@ -104,4 +104,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
     sys.exit(main())

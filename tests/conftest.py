@@ -2,8 +2,13 @@
 CPU device; multi-device behaviour is tested via subprocesses
 (tests/test_distributed.py) and the dry-run launcher owns its own flags."""
 import dataclasses
+import os
 
 import pytest
+
+# Tests never write JAX's persistent compilation cache: not in this process,
+# nor in the launcher subprocesses that inherit the environment.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 
 @pytest.fixture

@@ -21,12 +21,7 @@ from repro.training import optim
 
 
 def _compiled_flops(compiled) -> float:
-    """jax's Compiled.cost_analysis() returns a dict in newer versions and a
-    one-element list of dicts in older ones -- accept both."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return float(ca["flops"])
+    return float(compiled.cost_analysis()["flops"])
 
 
 def _unrolled_flops(cfg, B, T, kind):

@@ -24,12 +24,13 @@ def run_with_devices(code: str, n: int = 8, timeout: int = 420) -> str:
 def test_distributed_search_converges():
     out = run_with_devices("""
 import jax, numpy as np
+from repro.launch.mesh import auto_mesh
 from repro.core import env as env_lib, reinforce
 from repro.distributed import dist_search
 from repro.costmodel.layers import LayerSpec
 wl = [LayerSpec.conv(32,16,28,28,3,3), LayerSpec.dwconv(64,14,14,3,3),
       LayerSpec.gemm(64,256,128)]
-mesh = jax.make_mesh((4,2), ("data","model"))
+mesh = auto_mesh((4,2), ("data","model"))
 state, hist = dist_search.run_distributed_search(
     wl, env_lib.EnvConfig(platform="iot"), mesh,
     reinforce.ReinforceConfig(epochs=80, lr=3e-3),
@@ -45,11 +46,12 @@ print("OK", float(state.best_value))
 def test_straggler_masking_preserves_convergence():
     out = run_with_devices("""
 import jax, numpy as np
+from repro.launch.mesh import auto_mesh
 from repro.core import env as env_lib, reinforce
 from repro.distributed import dist_search
 from repro.costmodel.layers import LayerSpec
 wl = [LayerSpec.conv(32,16,28,28,3,3), LayerSpec.gemm(64,256,128)]
-mesh = jax.make_mesh((4,2), ("data","model"))
+mesh = auto_mesh((4,2), ("data","model"))
 mask = np.ones(8, bool); mask[[2,6]] = False
 state, hist = dist_search.run_distributed_search(
     wl, env_lib.EnvConfig(platform="iot"), mesh,
@@ -64,18 +66,18 @@ print("OK")
 def test_int8_psum_error_bound():
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from repro.launch.mesh import auto_mesh
 from jax.sharding import PartitionSpec as P
 from repro.distributed.dist_search import psum_int8
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = auto_mesh((8,), ("pod",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 def f(xs):
     local = xs[0]
     exact = jax.lax.psum(local, "pod")
     approx = psum_int8(local, "pod")
     return exact[None], approx[None]
-exact, approx = shard_map(f, mesh=mesh, in_specs=P("pod", None),
-                          out_specs=P("pod", None))(x)
+exact, approx = jax.shard_map(f, mesh=mesh, in_specs=P("pod", None),
+                              out_specs=P("pod", None))(x)
 err = float(jnp.abs(exact - approx).max())
 scale = float(jnp.abs(x).max()) / 127.0
 assert err <= 8 * scale * 0.51 + 1e-6, (err, scale)  # n * scale/2 bound
@@ -87,11 +89,12 @@ print("OK", err)
 def test_int8_compressed_pod_reduction_converges():
     out = run_with_devices("""
 import jax, numpy as np
+from repro.launch.mesh import auto_mesh
 from repro.core import env as env_lib, reinforce
 from repro.distributed import dist_search
 from repro.costmodel.layers import LayerSpec
 wl = [LayerSpec.conv(32,16,28,28,3,3), LayerSpec.gemm(64,256,128)]
-mesh = jax.make_mesh((2,2,2), ("pod","data","model"))
+mesh = auto_mesh((2,2,2), ("pod","data","model"))
 state, hist = dist_search.run_distributed_search(
     wl, env_lib.EnvConfig(platform="iot"), mesh,
     reinforce.ReinforceConfig(epochs=80, lr=3e-3),
@@ -109,13 +112,13 @@ def test_masked_int8_pod_reduction_matches_plain_masked_psum():
     hardcoded npods=2)."""
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from repro.launch.mesh import auto_mesh
 from jax.sharding import PartitionSpec as P
 from repro.distributed.dist_search import masked_psum, masked_hierarchical_psum
 
 def run_case(mesh_shape, axes, alive_np):
     n = int(np.prod(mesh_shape))
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = auto_mesh(mesh_shape, axes)
     x = jax.random.normal(jax.random.PRNGKey(0), (n, 64))
     def f(xs, al):
         local, a = xs[0], al[0]
@@ -123,9 +126,10 @@ def run_case(mesh_shape, axes, alive_np):
         comp = masked_hierarchical_psum({"g": local}, a, axes,
                                         compress=True)["g"]
         return plain[None], comp[None]
-    plain, comp = shard_map(f, mesh=mesh, in_specs=(P(axes, None), P(axes)),
-                            out_specs=(P(axes, None), P(axes, None)),
-                            check_rep=False)(x, jnp.asarray(alive_np))
+    plain, comp = jax.shard_map(
+        f, mesh=mesh, in_specs=(P(axes, None), P(axes)),
+        out_specs=(P(axes, None), P(axes, None)),
+        check_vma=False)(x, jnp.asarray(alive_np))
     plain, comp = np.asarray(plain[0]), np.asarray(comp[0])
     rel = np.abs(plain - comp).max() / max(np.abs(plain).max(), 1e-9)
     assert rel < 0.05, (mesh_shape, axes, rel)
@@ -206,6 +210,7 @@ def test_sharded_train_step_matches_single_device():
     """pjit train step on a (2,2) mesh == unsharded result."""
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np, dataclasses, functools
+from repro.launch.mesh import auto_mesh
 from repro import configs
 from repro.models import lm
 from repro.training import optim
@@ -220,7 +225,7 @@ batch = {"tokens": tokens, "labels": tokens}
 step = functools.partial(lm.train_step, cfg=cfg, optimizer=opt)
 p1, o1, l1 = jax.jit(step)(params, ost, batch)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = auto_mesh((2, 2), ("data", "model"))
 psh = sharding.tree_shardings(mesh, params)
 params_s = jax.device_put(params, psh)
 ost_s = jax.device_put(ost, sharding.tree_shardings(mesh, ost))

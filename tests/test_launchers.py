@@ -4,6 +4,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -58,6 +60,26 @@ def test_search_launcher(tmp_path):
     rec = json.load(open(out_file))
     assert rec["best_value"] <= rec["stage1_value"]
     assert len(rec["assignment"]["pe"]) == len(rec["assignment"]["layers"])
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_placement(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and no code overrides it; unset, the
+    cache goes to the one fixed path in the checkout."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(REPO, ".jax_cache")
+    if from_env:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    code = ("import jax\n"
+            "from repro.launch.compile_cache import enable_persistent_cache\n"
+            "print(enable_persistent_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, env=env, cwd=str(tmp_path))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want]
 
 
 def test_search_launcher_arch_target(tmp_path):

@@ -12,13 +12,14 @@ from jax.sharding import PartitionSpec as P
 
 from repro import configs
 from repro.distributed import sharding
+from repro.launch.mesh import auto_mesh
 from repro.models import lm
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # single CPU device: a (1,1) mesh still exercises all the rule logic
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def test_param_rules_match_paths(mesh):
@@ -38,7 +39,7 @@ def test_param_rules_match_paths(mesh):
 
 def test_divisibility_fallback():
     """A dim that doesn't divide the axis falls back, never errors."""
-    big = jax.make_mesh((1, 1), ("data", "model"))
+    big = auto_mesh((1, 1), ("data", "model"))
     # pretend-mesh of size 1 always divides; test assign_spec directly
     spec = sharding.assign_spec(big, (7, 13), ((("model",),), (("data",),)))
     assert spec == P("model", "data")  # size-1 axes divide everything

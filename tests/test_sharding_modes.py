@@ -15,7 +15,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_param_spec_modes():
     import jax
     from repro.distributed import sharding
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((1, 1), ("data", "model"))
 
     # tp: rules fire (divisibility-guarded; 1-sized axes always divide).
     spec = sharding.param_spec(mesh, "blocks/mlp/w_gate", (64, 256), "tp")
@@ -46,6 +47,7 @@ def test_all_modes_agree_numerically():
     """One train step under tp / fsdp / dp == the unsharded result."""
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np, dataclasses, functools
+from repro.launch.mesh import auto_mesh
 from repro import configs
 from repro.models import lm
 from repro.training import optim
@@ -61,7 +63,7 @@ batch = {"tokens": tokens, "labels": tokens}
 ref_step = functools.partial(lm.train_step, cfg=cfg, optimizer=opt)
 p_ref, _, l_ref = jax.jit(ref_step)(params, ost, batch)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = auto_mesh((4, 2), ("data", "model"))
 for mode in ("tp", "fsdp", "dp"):
     psh = sharding.tree_shardings(mesh, params, mode)
     params_s = jax.device_put(params, psh)
@@ -86,6 +88,7 @@ def test_remat_policies_agree():
     """full / dots / none remat produce identical losses and gradients."""
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np, dataclasses, functools
+from repro.launch.mesh import auto_mesh
 from repro import configs
 from repro.models import lm
 cfg = dataclasses.replace(configs.get_smoke("qwen1p5_0p5b"),
