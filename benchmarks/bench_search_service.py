@@ -102,9 +102,11 @@ def _telemetry_probe(eps: int):
     obs.disable()
     common.print_table(
         "Telemetry probe (instrumented service wave)",
-        ["method", "hard evals", "chunks", "cache hit rate", "jit compiles"],
+        ["method", "hard evals", "chunks", "cache hit rate", "jit compiles",
+         "jit s"],
         [[m, t.get("hard_evals"), t.get("chunks"),
-          t.get("cache_hit_rate"), t.get("jit_compiles")]
+          t.get("cache_hit_rate"), t.get("jit_compiles", 0),
+          t.get("jit_s", {}).get("sum", 0.0)]
          for m, t in telemetry.items()])
     return telemetry, trace_path, prom_path, snapshot
 

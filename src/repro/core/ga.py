@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core import chunk as chunk_lib
 from repro.core import env as env_lib
+from repro.obs import instrument as obs_instrument
 from repro.costmodel import dataflows as dfl
 
 
@@ -207,7 +208,9 @@ def run_chunked_engine(env, ecfg, engine: GAEngine, state,
     the XLA program); with ``eval_fn(pe, kt, df) -> (P,) fitness`` each
     generation decodes on the host, evaluates through the injected function
     (the service's cross-request batcher) and applies the same compiled
-    ``evolve`` step.  Both paths produce byte-identical states/histories:
+    ``evolve`` step; with telemetry on, each such generation is a
+    ``search.step`` span around a ``search.eval`` span of its eval_fn
+    wait.  Both paths produce byte-identical states/histories:
     the decode is the same table gather, the fitness values are bit-equal
     (asserted in tests/test_search_service.py), and every other op is the
     identical jnp program.
@@ -231,25 +234,32 @@ def run_chunked_engine(env, ecfg, engine: GAEngine, state,
     pe_table = np.asarray(env.pe_table, np.float32)
     kt_table = np.asarray(env.kt_table, np.float32)
 
+    step_s = obs_instrument.SEARCH_STEP_SECONDS
+    eval_s = obs_instrument.SEARCH_EVAL_WAIT_SECONDS
+
     def run_chunk(state, n):
         h = np.empty((n,), np.float32)
         for g in range(n):
-            pop = np.asarray(state.pop)
-            if raw_genome:
-                pe = pop[..., 0].astype(np.float32)
-                kt = pop[..., 1].astype(np.float32)
-            else:
-                pe = pe_table[pop[..., 0]]
-                kt = kt_table[pop[..., 1]]
-            if fixed_df is not None:
-                df = fixed_df
-            elif mix_df:
-                df = pop[..., 2].astype(np.float32)
-            else:
-                df = np.float32(ecfg.dataflow)
-            fit = np.asarray(eval_fn(pe, kt, df), np.float32)
-            state, bv = evolve(state, jnp.asarray(fit))
-            h[g] = np.float32(bv)
+            with obs_instrument.timed("search.step", step_s,
+                                      engine=engine_name):
+                pop = np.asarray(state.pop)
+                if raw_genome:
+                    pe = pop[..., 0].astype(np.float32)
+                    kt = pop[..., 1].astype(np.float32)
+                else:
+                    pe = pe_table[pop[..., 0]]
+                    kt = kt_table[pop[..., 1]]
+                if fixed_df is not None:
+                    df = fixed_df
+                elif mix_df:
+                    df = pop[..., 2].astype(np.float32)
+                else:
+                    df = np.float32(ecfg.dataflow)
+                with obs_instrument.timed("search.eval", eval_s,
+                                          engine=engine_name):
+                    fit = np.asarray(eval_fn(pe, kt, df), np.float32)
+                state, bv = evolve(state, jnp.asarray(fit))
+                h[g] = np.float32(bv)
         return state, h
 
     state, hist = chunk_lib.drive(

@@ -5,9 +5,10 @@ Three pieces, one switch:
   * **metrics** -- a process-wide registry of counters / gauges / fixed-
     bucket histograms with Prometheus text exposition and a JSON snapshot
     (:mod:`repro.obs.metrics`);
-  * **tracing** -- nested spans with monotonic timestamps over a ring
-    buffer, an optional JSONL sink, and a Chrome-trace/Perfetto export
-    (:mod:`repro.obs.trace`);
+  * **tracing** -- nested spans over a ring buffer, an optional JSONL
+    sink, and a Chrome-trace/Perfetto export; while telemetry is on, each
+    span is also a ``jax.profiler.TraceAnnotation``, so a profiler trace
+    shows it beside the device ops it launched (:mod:`repro.obs.trace`);
   * **flight recorder** -- a per-search accumulator whose summary lands in
     ``SearchOutcome.telemetry`` (:mod:`repro.obs.recorder`).
 
@@ -57,9 +58,10 @@ def enable(trace: bool = True, ring: int = 16384,
 
     ``trace=True`` installs a :class:`Tracer` (``ring`` spans of in-memory
     history; ``jsonl_path`` additionally streams every finished span to a
-    JSONL file).  Metrics and flight recorders activate either way.
-    Idempotent: re-enabling with ``trace=True`` keeps an already-installed
-    tracer unless a new ``jsonl_path`` is requested.
+    JSONL file).  Metrics, flight recorders, profiler annotations and the
+    JIT watcher (:func:`repro.obs.instrument.watch_jit`) activate either
+    way.  Idempotent: re-enabling with ``trace=True`` keeps an
+    already-installed tracer unless a new ``jsonl_path`` is requested.
     """
     if trace:
         t = _state.tracer
@@ -67,6 +69,7 @@ def enable(trace: bool = True, ring: int = 16384,
             if t is not None:
                 t.close()
             _state.tracer = Tracer(ring=ring, jsonl_path=jsonl_path)
+    instrument.watch_jit()
     _state.enabled = True
 
 
@@ -95,8 +98,7 @@ def save_trace(path: str) -> None:
 
 
 def reset() -> None:
-    """Test/bench helper: zero metrics, clear spans and compile tracking."""
+    """Test/bench helper: zero metrics and clear spans."""
     REGISTRY.reset()
-    instrument.reset_seen_programs()
     if _state.tracer is not None:
         _state.tracer.clear()

@@ -5,7 +5,7 @@ A :class:`FlightRecorder` rides along one search from submission to
 timings into it, the cost-eval batcher attributes queue-wait / dispatch /
 device time and cache hits to it (the recorder is captured at submit time,
 so a dispatch fused across N searches credits each rider its own share),
-and the JIT-compile tracker notes first-compile events.  The final
+and the JIT watcher notes JAX's compile events.  The final
 :meth:`summary` dict lands in ``SearchOutcome.telemetry``.
 
 Attribution across threads: the *search worker* thread installs its

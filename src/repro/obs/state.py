@@ -16,8 +16,9 @@ from typing import Optional
 # shared null context manager, and no recorder is installed.
 enabled: bool = False
 
-# The active Tracer (``repro.obs.trace.Tracer``) or None.  Spans are only
-# recorded when BOTH ``enabled`` is True and a tracer is installed.
+# The active Tracer (``repro.obs.trace.Tracer``) or None.  Spans reach its
+# ring only when BOTH ``enabled`` is True and a tracer is installed; the
+# profiler annotation of a span needs ``enabled`` alone.
 tracer: Optional[object] = None
 
 

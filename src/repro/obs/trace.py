@@ -1,25 +1,36 @@
-"""Structured tracing: nested spans over a ring buffer, JSONL + Chrome export.
+"""Structured tracing: nested spans over a ring buffer, JSONL + Chrome export,
+and the same spans on the JAX profiler's timeline.
 
 A *span* is one named, timed region with attributes -- ``batcher.dispatch``,
-``search.chunk``, ``xla.dispatch`` -- recorded with monotonic
-``time.perf_counter_ns`` timestamps so durations are immune to wall-clock
-jumps.  Spans nest per thread (a thread-local stack tracks depth and parent)
-and land in:
+``search.chunk``, ``xla.dispatch`` -- whose duration comes from the
+monotonic ``time.perf_counter_ns`` (immune to wall-clock jumps).  Spans nest
+per thread (a thread-local stack tracks depth and parent) and land in:
 
   * an in-memory ring buffer (``collections.deque(maxlen=...)`` -- bounded,
     allocation-cheap, safe to leave on for long service runs);
   * optionally a JSONL trace file, one JSON object per finished span,
     appended under a lock (multi-thread safe);
   * on demand, a Chrome-trace JSON export loadable in ``chrome://tracing``
-    or https://ui.perfetto.dev (``ph: "X"`` complete events).
+    or https://ui.perfetto.dev (``ph: "X"`` complete events);
+  * the JAX profiler: while telemetry is on, every span also opens a
+    ``jax.profiler.TraceAnnotation`` of the same name, with or without a
+    ring tracer installed, so a profiler session (``jax.profiler.trace``)
+    records it in the ``.xplane.pb`` host plane beside the device ops it
+    launched.
+
+Clock: a ring record's ``ts_us`` is ``time.time_ns()`` at the span's start,
+the clock the profiler stamps its events with.  An ``.xplane.pb`` stores its
+event times as offsets from the session's start (the ``profile_start_time``
+stat of its ``Task Environment`` plane, :func:`profile_start_ns`), so
+``ts_us * 1e3 - profile_start_ns(...)`` is the span's start on the
+profile's timeline: one constant offset per profiler session.
 
 Recording is observational only: spans never touch RNG state, search state
-or any value the engines compute.  When tracing is disabled, ``span()``
+or any value the engines compute.  When telemetry is disabled, ``span()``
 returns one shared null context manager -- no allocation, no clock read.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
@@ -29,10 +40,28 @@ from typing import Dict, List, Optional
 
 from repro.obs import state as _state
 
-# Offset perf_counter timestamps to an epoch-ish origin once per process so
-# trace files from one run share a common, comparable timebase.
-_T0_NS = time.perf_counter_ns()
-_EPOCH_US = time.time() * 1e6
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` (JAX imported on first use)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
+
+
+def profile_start_ns(profile) -> Optional[int]:
+    """The ``time.time_ns()`` at which the session of a
+    ``jax.profiler.ProfileData`` started: add it to an event's ``start_ns``
+    to put the event on the ring's clock.  None when the profile lacks it."""
+    for plane in profile.planes:
+        if plane.name == "Task Environment":
+            for key, value in plane.stats:
+                if key == "profile_start_time":
+                    return int(value)
+    return None
 
 
 class _NullSpan:
@@ -53,10 +82,32 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """One live span; finished records are plain dicts in the ring."""
+class _ProfilerSpan:
+    """Telemetry on without a ring tracer: the profiler annotation alone."""
 
-    __slots__ = ("tracer", "name", "attrs", "t0", "parent", "depth", "tid")
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = _annotation(name)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs):
+        return self
+
+
+class _Span:
+    """One live span; finished records are plain dicts in the ring.  Its
+    profiler annotation encloses the ring's interval."""
+
+    __slots__ = ("tracer", "name", "attrs", "ts", "t0", "parent", "depth",
+                 "tid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict):
         self.tracer = tracer
@@ -77,15 +128,20 @@ class _Span:
         self.depth = len(stack)
         self.tid = threading.get_ident()
         stack.append(self)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self.ts = time.time_ns()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self.t0
+        self._ann.__exit__(*exc)
         stack = self.tracer._tls.stack
         if stack and stack[-1] is self:
             stack.pop()
-        self.tracer._record(self, dur)
+        self.tracer._record(self.name, self.ts, dur, self.tid, self.depth,
+                            self.parent, self.attrs)
         return False
 
 
@@ -108,18 +164,28 @@ class Tracer:
     def span(self, name: str, **attrs) -> _Span:
         return _Span(self, name, attrs)
 
-    def _record(self, span: _Span, dur_ns: int) -> None:
+    def add(self, name: str, ts_ns: int, dur_ns: int, **attrs) -> None:
+        """Record a span timed elsewhere (``ts_ns`` on ``time.time_ns``'s
+        clock), nested under this thread's open span -- JAX's own compile
+        events arrive this way."""
+        stack = getattr(self._tls, "stack", None)
+        self._record(name, ts_ns, dur_ns, threading.get_ident(),
+                     len(stack) if stack else 0,
+                     stack[-1].name if stack else None, attrs)
+
+    def _record(self, name: str, ts_ns: int, dur_ns: int, tid: int,
+                depth: int, parent: Optional[str], attrs: Dict) -> None:
         rec = {
-            "name": span.name,
-            "ts_us": round((span.t0 - _T0_NS) / 1e3 + _EPOCH_US, 3),
+            "name": name,
+            "ts_us": round(ts_ns / 1e3, 3),
             "dur_us": round(dur_ns / 1e3, 3),
-            "tid": span.tid,
-            "depth": span.depth,
+            "tid": tid,
+            "depth": depth,
         }
-        if span.parent is not None:
-            rec["parent"] = span.parent
-        if span.attrs:
-            rec["attrs"] = {k: _jsonable(v) for k, v in span.attrs.items()}
+        if parent is not None:
+            rec["parent"] = parent
+        if attrs:
+            rec["attrs"] = {k: _jsonable(v) for k, v in attrs.items()}
         with self._lock:
             if len(self._ring) == self._ring.maxlen:
                 self.dropped += 1
@@ -182,22 +248,14 @@ def _jsonable(v):
 def span(name: str, **attrs):
     """The module-level span entry point every call site uses.
 
-    Disabled (no tracer or telemetry off) -> the shared :data:`NULL_SPAN`;
-    enabled -> a real span on the installed tracer.  Always usable as
+    Telemetry off -> the shared :data:`NULL_SPAN`; on -> a span on the
+    profiler's timeline, recorded in the installed tracer's ring too when
+    there is one.  Always usable as
     ``with obs.span("x", k=v) as sp: sp.set(more=...)``.
     """
-    tracer = _state.tracer
-    if tracer is None or not _state.enabled:
+    if not _state.enabled:
         return NULL_SPAN
+    tracer = _state.tracer
+    if tracer is None:
+        return _ProfilerSpan(name)
     return tracer.span(name, **attrs)
-
-
-@contextlib.contextmanager
-def timed(out: dict, key: str):
-    """Tiny helper: time a block into ``out[key]`` (seconds) -- used where a
-    duration is needed even without a tracer installed."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        out[key] = time.perf_counter() - t0

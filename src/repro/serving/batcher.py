@@ -93,6 +93,14 @@ def _flat_cost(layers, pe, kt, df):
     return jnp.stack([out.latency, out.energy, out.area, out.power], axis=-1)
 
 
+def _phase(name: str):
+    """One phase of a fused dispatch: span ``batcher.<name>`` plus
+    ``repro_batcher_phase_seconds{phase=<name>}``."""
+    return obs_instrument.timed(f"batcher.{name}",
+                                obs_instrument.BATCHER_PHASE_SECONDS,
+                                phase=name)
+
+
 def _next_pow2(n: int, lo: int = 256) -> int:
     m = lo
     while m < n:
@@ -283,53 +291,63 @@ class CostEvalBatcher:
 
     def _dispatch(self, items: List[_Item]) -> None:
         t0 = time.perf_counter() if obs_state.enabled else 0.0
-        sp = obs_trace.span("batcher.dispatch").__enter__()
-        rows = (items[0].points if len(items) == 1
-                else np.concatenate([it.points for it in items], axis=0))
-        uniq, inv = np.unique(rows, axis=0, return_inverse=True)
-        keys = [u.tobytes() for u in uniq]
-        values, miss_index = self.cache.get_many(keys)
-        t_eval = 0.0
-        if miss_index:
-            te = time.perf_counter() if obs_state.enabled else 0.0
-            fresh = self._eval_points(uniq[miss_index])
+        with obs_trace.span("batcher.dispatch") as sp:
+            with _phase("dedup"):
+                rows = (items[0].points if len(items) == 1
+                        else np.concatenate([it.points for it in items],
+                                            axis=0))
+                uniq, inv = np.unique(rows, axis=0, return_inverse=True)
+                keys = [u.tobytes() for u in uniq]
+            with _phase("lookup"):
+                values, miss_index = self.cache.get_many(keys)
+            t_eval = 0.0
+            if miss_index:
+                with _phase("eval"):
+                    te = time.perf_counter() if obs_state.enabled else 0.0
+                    fresh = self._eval_points(uniq[miss_index])
+                    if obs_state.enabled:
+                        t_eval = time.perf_counter() - te
+            with _phase("fill"):
+                if miss_index:
+                    # Cache per-row COPIES: a row view would pin the whole
+                    # dispatch's result array in memory for as long as any
+                    # one point stays hot.
+                    self.cache.put_many([keys[i] for i in miss_index],
+                                        [f.copy() for f in fresh])
+                    for i, v in zip(miss_index, fresh):
+                        values[i] = v
+                per_point = np.stack(values)[inv]          # (P, 4)
+            sp.set(items=len(items), points=len(rows), unique=len(uniq),
+                   fresh=len(miss_index))
             if obs_state.enabled:
-                t_eval = time.perf_counter() - te
-            # Cache per-row COPIES: a row view would pin the whole dispatch's
-            # result array in memory for as long as any one point stays hot.
-            self.cache.put_many([keys[i] for i in miss_index],
-                                [f.copy() for f in fresh])
-            for i, v in zip(miss_index, fresh):
-                values[i] = v
-        per_point = np.stack(values)[inv]          # (P, 4)
-        sp.set(items=len(items), points=len(rows), unique=len(uniq),
-               fresh=len(miss_index)).__exit__(None, None, None)
-        if obs_state.enabled:
-            self._record_dispatch(items, t0, time.perf_counter() - t0,
-                                  t_eval, len(uniq), miss_index, inv)
+                # repro_batcher_dispatch_seconds ends here, before the
+                # aggregation (batcher.aggregate times that part).
+                self._record_dispatch(items, t0, time.perf_counter() - t0,
+                                      t_eval, len(uniq), miss_index, inv)
 
-        with self._stats_lock:
-            s = self._stats
-            s["dispatches"] += 1
-            s["fused_dispatches"] += len(items) > 1
-            s["items"] += len(items)
-            s["points"] += len(rows)
-            s["unique_points"] += len(uniq)
-            s["fresh_points"] += len(miss_index)
-            s["max_items_per_dispatch"] = max(
-                s["max_items_per_dispatch"], len(items))
-            s["max_points_per_dispatch"] = max(
-                s["max_points_per_dispatch"], len(rows))
+            with self._stats_lock:
+                s = self._stats
+                s["dispatches"] += 1
+                s["fused_dispatches"] += len(items) > 1
+                s["items"] += len(items)
+                s["points"] += len(rows)
+                s["unique_points"] += len(uniq)
+                s["fresh_points"] += len(miss_index)
+                s["max_items_per_dispatch"] = max(
+                    s["max_items_per_dispatch"], len(items))
+                s["max_points_per_dispatch"] = max(
+                    s["max_points_per_dispatch"], len(rows))
 
-        off = 0
-        for it in items:
-            n = it.points.shape[0]
-            vals = per_point[off:off + n].reshape(it.shape + (4,))
-            off += n
-            agg = _agg_multi_fn(it.agg_key) if it.multi else _agg_fn(
-                it.agg_key)
-            it.fit = np.asarray(agg(jnp.asarray(vals), it.budget))
-            it.event.set()
+            with _phase("aggregate"):
+                off = 0
+                for it in items:
+                    n = it.points.shape[0]
+                    vals = per_point[off:off + n].reshape(it.shape + (4,))
+                    off += n
+                    agg = _agg_multi_fn(it.agg_key) if it.multi else _agg_fn(
+                        it.agg_key)
+                    it.fit = np.asarray(agg(jnp.asarray(vals), it.budget))
+                    it.event.set()
 
     def _record_dispatch(self, items: List[_Item], t0: float, dt: float,
                          t_eval: float, n_uniq: int, miss_index, inv) -> None:
@@ -398,7 +416,7 @@ def eval_point_rows(rows: np.ndarray, use_kernel: bool) -> np.ndarray:
         pad = np.ones((Mp - M, ROW_WIDTH), np.float32)
         pad[:, NUM_FIELDS - 1] = 0.0            # repeat=0: benign rows
         rp = np.concatenate([rows, pad], axis=0) if Mp > M else rows
-        with obs_instrument.dispatch_span("cost_eval_kernel", key=Mp):
+        with obs_instrument.dispatch_span("cost_eval_kernel"):
             lat, en, area, pw = ops.batched_cost_multi(
                 rp[:, :NUM_FIELDS].reshape(-1, TN, NUM_FIELDS),
                 rp[:, _PE_COL].reshape(-1, TN),
@@ -412,7 +430,7 @@ def eval_point_rows(rows: np.ndarray, use_kernel: bool) -> np.ndarray:
     Mp = _next_pow2(M)
     rp = np.ones((Mp, ROW_WIDTH), np.float32)
     rp[:M] = rows
-    with obs_instrument.dispatch_span("cost_eval_jnp", key=Mp):
+    with obs_instrument.dispatch_span("cost_eval_jnp"):
         out = _flat_cost(rp[:, :NUM_FIELDS], rp[:, _PE_COL],
                          rp[:, _KT_COL], rp[:, _DF_COL])
         out = np.asarray(out)

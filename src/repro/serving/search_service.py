@@ -138,6 +138,7 @@ class SearchTicket:
         self.status = "queued"     # queued|running|done|cancelled|failed
         self.trials: List[api_types.Trial] = []
         self.submitted_at = time.time()
+        self.started_at: Optional[float] = None   # set when a worker claims it
         self.wall_seconds = 0.0
         self._outcome: Optional[api_types.SearchOutcome] = None
         self._error: Optional[BaseException] = None
@@ -205,6 +206,7 @@ class SearchTicket:
                 return False
             self._started = True
             self.status = "running"
+            self.started_at = time.time()
             return True
 
     def _finish(self, status: str, outcome=None, error=None) -> bool:
@@ -323,6 +325,8 @@ class SearchService:
     def _run(self, ticket: SearchTicket) -> None:
         if not ticket._begin():
             return   # cancelled while queued: already finished and counted
+        obs_instrument.SERVICE_QUEUE_WAIT.observe(
+            ticket.started_at - ticket.submitted_at)
         obs_instrument.SERVICE_ACTIVE.inc()
         sp = obs_trace.span("service.search", uid=ticket.uid,
                             method=ticket.request.method).__enter__()

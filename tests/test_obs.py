@@ -11,6 +11,7 @@ import importlib.util
 import json
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -215,23 +216,90 @@ def test_recording_is_thread_local_and_gated():
 
 
 # ---------------------------------------------------------------------------
-# Dispatch/compile tracking.
+# Dispatch timing + JAX's own compile events.
 # ---------------------------------------------------------------------------
-def test_dispatch_span_counts_first_sighting_as_compile():
+def test_dispatch_span_times_every_dispatch():
     _enabled()
     rec = recorder.FlightRecorder()
     with recorder.recording(rec):
-        for _ in range(3):
-            with instrument.dispatch_span("t_prog", key=256):
+        for _ in range(4):
+            with instrument.dispatch_span("t_prog"):
                 pass
-        with instrument.dispatch_span("t_prog", key=512):
-            pass
-    assert instrument.JIT_COMPILES.value(program="t_prog") == 2.0
     assert instrument.DISPATCH_SECONDS.stats(program="t_prog")["count"] == 4
-    assert rec.count("jit_compiles") == 2.0
+    assert rec.summary()["t_prog_dispatch_s"]["count"] == 4
+    assert "jit_compiles" not in rec.summary()       # timing is no compile
     spans = [s for s in obs.tracer().spans() if s["name"] == "xla.dispatch"]
-    assert [s["attrs"]["compile"] for s in spans] == [
-        True, False, False, True]
+    assert [s["attrs"] for s in spans] == [{"program": "t_prog"}] * 4
+
+
+def test_jit_watcher_counts_real_compiles():
+    """A fresh jax.jit is one backend compile with positive seconds; its
+    second call compiles nothing and counts nothing."""
+    import jax
+
+    _enabled()
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = np.arange(7, dtype=np.float32)
+    rec = recorder.FlightRecorder()
+    with recorder.recording(rec):
+        f(x).block_until_ready()
+    compiled = instrument.JIT_COMPILES.value(result="compiled")
+    compile_s = instrument.JIT_SECONDS.stats(phase="compile")
+    assert compiled == 1.0
+    assert instrument.JIT_COMPILES.value(result="cache_hit") == 0.0
+    assert compile_s["count"] == 1 and compile_s["sum"] > 0.0
+    for phase in ("trace", "lower"):
+        assert instrument.JIT_SECONDS.stats(phase=phase)["count"] >= 1
+    summary = rec.summary()
+    assert summary["jit_compiles"] == 1 and summary["jit_s"]["sum"] > 0.0
+    names = [s["name"] for s in obs.tracer().spans()]
+    assert names.count("jit.compile") == 1
+    (sp,) = [s for s in obs.tracer().spans() if s["name"] == "jit.compile"]
+    assert "lambda" in sp["attrs"]["fun_name"] and sp["dur_us"] > 0
+
+    f(x + 1.0).block_until_ready()
+    assert instrument.JIT_COMPILES.value(result="compiled") == compiled
+    assert instrument.JIT_SECONDS.stats(phase="compile") == compile_s
+
+
+def test_jit_watcher_cache_hits_and_nested_events():
+    """JAX's persistent-cache hit inside a backend compile counts as
+    ``cache_hit``; an event nested in another counts once, in the inner
+    one; nothing counts while telemetry is off."""
+    from jax import monitoring
+
+    trace_ev = "/jax/core/compile/jaxpr_trace_duration"
+    lower_ev = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    compile_ev = "/jax/core/compile/backend_compile_duration"
+    obs.enable(trace=False)
+    t = time.time()
+    monitoring.record_event_time_span(trace_ev, t + 0.2, t + 0.5,
+                                      fun_name="inner")
+    monitoring.record_event_time_span(trace_ev, t + 0.0, t + 1.0,
+                                      fun_name="outer")
+    monitoring.record_event_time_span(trace_ev, t + 1.5, t + 1.75,
+                                      fun_name="in_lowering")
+    monitoring.record_event_time_span(lower_ev, t + 1.0, t + 2.0,
+                                      fun_name="outer")
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event_time_span(compile_ev, t + 2.0, t + 2.5,
+                                      fun_name="outer")
+    monitoring.record_event_time_span(compile_ev, t + 3.0, t + 5.0,
+                                      fun_name="other")
+    tr = instrument.JIT_SECONDS.stats(phase="trace")
+    assert tr["count"] == 3 and tr["sum"] == pytest.approx(1.25)
+    assert instrument.JIT_SECONDS.stats(phase="lower")["sum"] == \
+        pytest.approx(0.75)
+    assert instrument.JIT_SECONDS.stats(phase="compile")["sum"] == \
+        pytest.approx(2.5)
+    assert instrument.JIT_COMPILES.value(result="cache_hit") == 1.0
+    assert instrument.JIT_COMPILES.value(result="compiled") == 1.0
+
+    obs.disable()
+    monitoring.record_event_time_span(compile_ev, t + 6.0, t + 7.0,
+                                      fun_name="off")
+    assert instrument.JIT_COMPILES.value(result="compiled") == 1.0
+    assert instrument.JIT_SECONDS.stats(phase="compile")["count"] == 2
 
 
 def test_hard_evals_helper_feeds_registry_and_recorder():
@@ -344,6 +412,161 @@ def test_batcher_hammer_exact_counters_and_attribution():
             assert len(out) == K and all(f.shape == (B,) for f in out)
     finally:
         b.close()
+
+
+def test_batcher_phases_sum_to_dispatch_seconds():
+    """dedup + lookup + eval + fill is the dispatch histogram's interval;
+    aggregate follows it, inside the ``batcher.dispatch`` span."""
+    _enabled()
+    env = env_lib.make_env(workloads.get_workload("resnet50"), ECFG)
+    layers = np.asarray(env.layers, np.float32)
+    N = layers.shape[0]
+    rng = np.random.default_rng(5)
+    b = CostEvalBatcher(window_ms=0.0, use_kernel=False)
+    try:
+        for _ in range(6):
+            pe = rng.integers(1, 64, (64, N)).astype(np.float32)
+            kt = rng.integers(1, 64, (64, N)).astype(np.float32)
+            b.evaluate(layers, pe, kt, 0.0, ECFG, env.budget)
+        n = b.stats()["dispatches"]
+    finally:
+        b.close()
+    ph = instrument.BATCHER_PHASE_SECONDS
+    parts = sum(ph.stats(phase=p)["sum"]
+                for p in ("dedup", "lookup", "eval", "fill"))
+    whole = instrument.BATCHER_DISPATCH_SECONDS.stats()
+    assert whole["count"] == n
+    assert parts == pytest.approx(whole["sum"], rel=0.05)
+    for p in ("dedup", "lookup", "eval", "fill", "aggregate"):
+        assert ph.stats(phase=p)["count"] == n, p
+    spans = obs.tracer().spans()
+    assert sum(s["name"] == "batcher.aggregate" for s in spans) == n
+    assert all(s["parent"] == "batcher.dispatch" for s in spans
+               if s["name"].startswith("batcher.")
+               and s["name"] != "batcher.dispatch")
+
+
+def test_service_queue_wait_records_the_second_tickets_wait():
+    from repro.serving import SearchService, ServiceConfig
+
+    _enabled()
+    req = [api.SearchRequest(workload="ncf", env=ECFG, eps=40, seed=s,
+                             method="ga", options={"population": 20})
+           for s in (0, 1)]
+    with SearchService(ServiceConfig(max_workers=1)) as svc:
+        t1, t2 = svc.submit(req[0]), svc.submit(req[1])
+        t1.result()
+        t2.result()
+    waits = [t.started_at - t.submitted_at for t in (t1, t2)]
+    assert t2.started_at >= t1.submitted_at + t1.wall_seconds  # t1's end
+    st = instrument.SERVICE_QUEUE_WAIT.stats()
+    assert st["count"] == 2
+    assert st["sum"] == pytest.approx(sum(waits))
+    assert st["max"] == pytest.approx(waits[1]) and waits[1] > waits[0]
+
+
+def test_engine_steps_split_eval_wait_from_host_time():
+    from repro.serving import SearchService, ServiceConfig
+
+    _enabled()
+    reqs = [api.SearchRequest(workload="ncf", env=ECFG, eps=eps, seed=0,
+                              method=m, options={"population": pop})
+            for m, eps, pop in (("ga", 60, 20), ("nsga2", 64, 16))]
+    with SearchService(ServiceConfig(max_workers=2)) as svc:
+        svc.run_all(reqs)
+    for engine, gens in (("ga", 3), ("nsga2", 4)):
+        step = instrument.SEARCH_STEP_SECONDS.stats(engine=engine)
+        wait = instrument.SEARCH_EVAL_WAIT_SECONDS.stats(engine=engine)
+        assert step["count"] == wait["count"] == gens, engine
+        assert 0.0 < wait["sum"] < step["sum"]
+    spans = obs.tracer().spans()
+    assert all(s["parent"] == "search.step" for s in spans
+               if s["name"] == "search.eval")
+    assert all(s["parent"] == "search.chunk" for s in spans
+               if s["name"] == "search.step")
+
+
+# ---------------------------------------------------------------------------
+# Spans on the JAX profiler's timeline.
+# ---------------------------------------------------------------------------
+PROFILED = ("service.search", "search.run", "search.chunk", "search.step",
+            "search.eval", "batcher.dispatch", "batcher.dedup",
+            "batcher.lookup", "batcher.eval", "batcher.fill",
+            "batcher.aggregate")
+
+
+def _profiled_service_run(log_dir):
+    """One GA ticket through a service, under a profiler session; returns
+    the profile's program-span events {name: [(start, end, line)]} (ns
+    from the session's start) and the session's start on time.time_ns."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from repro.serving import SearchService, ServiceConfig
+
+    req = [api.SearchRequest(workload="ncf", env=ECFG, eps=60, seed=s,
+                             method="ga", options={"population": 20})
+           for s in (4, 5)]
+    with SearchService(ServiceConfig(max_workers=1)) as svc:
+        svc.run_all(req[:1])            # compiles outside the session
+        with jax.profiler.trace(str(log_dir)):
+            svc.run_all(req[1:])        # fresh points: batcher.eval runs
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    events = {}
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name in instrument.SPAN_NAMES:
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns, (plane.name, i)))
+    return events, trace_mod.profile_start_ns(pd)
+
+
+def test_spans_on_the_profiler_timeline_match_the_ring(tmp_path):
+    """Every program span of a service run is in the .xplane.pb host plane,
+    as often as in the ring, nested as in the ring, starting at the ring's
+    time less one constant (the session's start) and lasting as long."""
+    _enabled()
+    events, start_ns = _profiled_service_run(tmp_path)
+    assert start_ns is not None
+    t0 = start_ns / 1e3
+    ring = [s for s in obs.tracer().spans()
+            if s["name"] in PROFILED and s["ts_us"] >= t0]
+    for name in PROFILED:
+        got = sorted(events.get(name, []))
+        want = sorted((s for s in ring if s["name"] == name),
+                      key=lambda s: s["ts_us"])
+        assert got and len(got) == len(want), name
+        for (s, e, _), r in zip(got, want):
+            dur_ns = r["dur_us"] * 1e3
+            assert abs((e - s) - dur_ns) <= max(0.1 * dur_ns, 1e6), name
+            assert abs(s - (r["ts_us"] * 1e3 - start_ns)) <= 1e6, name
+    parents = {r["name"]: r["parent"] for r in ring if "parent" in r}
+    for child, parent in parents.items():
+        outer = events[parent]
+        for s, e, line in events[child]:
+            assert any(ps <= s and e <= pe and pl == line
+                       for ps, pe, pl in outer), (child, parent)
+    assert parents["batcher.aggregate"] == "batcher.dispatch"
+    assert parents["search.eval"] == "search.step"
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_profiler_spans_follow_the_switch_alone(tmp_path, on):
+    """``obs.enable(trace=False)`` installs no ring, and the profiler still
+    gets every span; ``obs.disable()`` leaves none in it."""
+    obs_state.tracer = None             # as in a process that never traced
+    if on:
+        obs.enable(trace=False)
+    events, _ = _profiled_service_run(tmp_path)
+    assert obs.tracer() is None
+    for name in PROFILED:
+        assert bool(events.get(name)) is on, name
 
 
 # ---------------------------------------------------------------------------

@@ -8,9 +8,9 @@ Checks, exiting nonzero on the first failure:
 
   * the trace parses line-by-line as JSON objects carrying the span schema
     (``name``/``ts_us``/``dur_us``/``tid``/``depth``) with non-negative
-    durations and known span names (the taxonomy in
-    ``repro.obs.instrument.SPAN_NAMES`` plus ``xla.dispatch`` program
-    spans);
+    durations and depths (names are the taxonomy in
+    ``repro.obs.instrument.SPAN_NAMES``, JAX's compile events as
+    ``jit.trace``/``jit.lower``/``jit.compile`` among them);
   * the metrics file is well-formed Prometheus text exposition: every
     sample is preceded by ``# HELP`` / ``# TYPE`` comments for its metric,
     sample lines match ``name{labels} value``, histogram ``_bucket``
