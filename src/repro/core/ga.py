@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core import chunk as chunk_lib
 from repro.core import env as env_lib
+from repro.core import programs
 from repro.obs import instrument as obs_instrument
 from repro.costmodel import dataflows as dfl
 
@@ -188,10 +189,48 @@ def make_ga_engine(env: env_lib.EnvArrays, ecfg: env_lib.EnvConfig,
     return GAEngine(init_carry, gen_step, decode, fitness, evolve)
 
 
-def run_chunked_engine(env, ecfg, engine: GAEngine, state,
+class EnginePrograms(NamedTuple):
+    """A population engine and its jitted programs, kept per program key."""
+
+    name: str                    # engine label of metrics and spans
+    engine: GAEngine
+    scan_chunk: Callable         # jit: (state, n) -> n-generation scan
+    evolve: Callable             # jit: engine.evolve
+
+
+def engine_programs(name: str, make_engine: Callable, env, ecfg, *args,
+                    cfg) -> EnginePrograms:
+    """``make_engine(env, ecfg, *args, cfg)`` and its jitted programs, from
+    the ``name`` engine's process-wide program cache
+    (:mod:`repro.core.programs`).
+
+    The key is exactly what ``make_engine`` is given: ``ecfg``, ``cfg`` with
+    its seed and number of generations zeroed (they reach the program only
+    through its state and the static chunk length, and ``make_engine`` sees
+    the zeroed copy), and the contents of ``env`` and ``args``.  A search of
+    a key seen before gets the earlier search's engine and ``jax.jit``
+    objects, so it traces nothing.
+    """
+    cfg = dataclasses.replace(cfg, seed=0, generations=0)
+    key = (ecfg, cfg, programs.digest(*env, *args))
+
+    def build():
+        engine = make_engine(env, ecfg, *args, cfg)
+
+        @functools.partial(jax.jit, static_argnames=("n",))
+        def scan_chunk(state, n):
+            return jax.lax.scan(engine.gen_step, state, None, length=n)
+
+        return EnginePrograms(name, engine, scan_chunk,
+                              jax.jit(engine.evolve))
+
+    return programs.cache(name).get(key, build)
+
+
+def run_chunked_engine(env, ecfg, progs: EnginePrograms, state,
                        generations: int, chunk: Optional[int], on_chunk,
                        eval_fn, mix_df: bool, raw_genome: bool = False,
-                       fixed_df=None, engine_name: str = "ga"):
+                       fixed_df=None):
     """Shared chunk driver for every population engine.  Returns
     (state, (gens,) history).
 
@@ -201,7 +240,7 @@ def run_chunked_engine(env, ecfg, engine: GAEngine, state,
     (scalar (P,) or multi-objective (P, 4)) gets chunking, resume,
     cancellation and eval_fn injection from this one loop (via
     :func:`repro.core.chunk.drive`, which also tags each chunk's telemetry
-    with ``engine_name`` -- one hard eval per population member per
+    with ``progs.name`` -- one hard eval per population member per
     generation).
 
     ``eval_fn=None`` scans ``gen_step`` in jitted chunks (fitness stays in
@@ -214,15 +253,15 @@ def run_chunked_engine(env, ecfg, engine: GAEngine, state,
     the decode is the same table gather, the fitness values are bit-equal
     (asserted in tests/test_search_service.py), and every other op is the
     identical jnp program.
+
+    ``progs`` comes from :func:`engine_programs`: the jitted ``gen_step``
+    scan or ``evolve`` is the one every search of its key shares.
     """
     pop_size = int(state.pop.shape[0])
+    engine_name = progs.name
     if eval_fn is None:
-        @functools.partial(jax.jit, static_argnames=("n",))
-        def scan_chunk(state, n):
-            return jax.lax.scan(engine.gen_step, state, None, length=n)
-
         def run_chunk(state, n):
-            state, h = scan_chunk(state, n)
+            state, h = progs.scan_chunk(state, n)
             return state, np.asarray(h)
 
         state, hist = chunk_lib.drive(
@@ -230,7 +269,7 @@ def run_chunked_engine(env, ecfg, engine: GAEngine, state,
             engine=engine_name, evals_per_step=pop_size)
         return state, chunk_lib.concat_hist(hist)
 
-    evolve = jax.jit(engine.evolve)
+    evolve = progs.evolve
     pe_table = np.asarray(env.pe_table, np.float32)
     kt_table = np.asarray(env.kt_table, np.float32)
 
@@ -288,12 +327,11 @@ def run_ga_search(workload, ecfg: env_lib.EnvConfig,
     """
     if env is None:
         env = env_lib.make_env(workload, ecfg)
-    engine = make_ga_engine(env, ecfg, cfg)
+    progs = engine_programs("ga", make_ga_engine, env, ecfg, cfg=cfg)
     if state is None:
-        state = engine.init_carry(cfg.seed)
-    return run_chunked_engine(env, ecfg, engine, state, cfg.generations,
-                              chunk, on_chunk, eval_fn, mix_df=ecfg.mix,
-                              engine_name="ga")
+        state = progs.engine.init_carry(cfg.seed)
+    return run_chunked_engine(env, ecfg, progs, state, cfg.generations,
+                              chunk, on_chunk, eval_fn, mix_df=ecfg.mix)
 
 
 def ga_solution(env: env_lib.EnvArrays, ecfg: env_lib.EnvConfig,
@@ -403,14 +441,14 @@ def run_local_ga(workload, ecfg: env_lib.EnvConfig,
     """
     if env is None:
         env = env_lib.make_env(workload, ecfg)
-    engine = make_local_ga_engine(env, ecfg, init_pe, init_kt, init_df, cfg)
+    progs = engine_programs("local_ga", make_local_ga_engine, env, ecfg,
+                            init_pe, init_kt, init_df, cfg=cfg)
     if state is None:
-        state = engine.init_carry(cfg.seed)
+        state = progs.engine.init_carry(cfg.seed)
     fixed_df = np.asarray(init_df, np.float32) if eval_fn is not None else None
-    return run_chunked_engine(env, ecfg, engine, state, cfg.generations,
+    return run_chunked_engine(env, ecfg, progs, state, cfg.generations,
                               chunk, on_chunk, eval_fn, mix_df=False,
-                              raw_genome=True, fixed_df=fixed_df,
-                              engine_name="local_ga")
+                              raw_genome=True, fixed_df=fixed_df)
 
 
 def local_ga(workload, ecfg: env_lib.EnvConfig,

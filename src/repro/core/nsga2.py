@@ -411,13 +411,13 @@ def run_nsga2_search(workload, ecfg: env_lib.EnvConfig,
     """
     if env is None:
         env = env_lib.make_env(workload, ecfg)
-    engine = make_nsga2_engine(env, ecfg, cfg)
+    progs = ga_lib.engine_programs("nsga2", make_nsga2_engine, env, ecfg,
+                                   cfg=cfg)
     if state is None:
-        state = engine.init_carry(cfg.seed)
-    return ga_lib.run_chunked_engine(env, ecfg, engine, state,
+        state = progs.engine.init_carry(cfg.seed)
+    return ga_lib.run_chunked_engine(env, ecfg, progs, state,
                                      cfg.generations, chunk, on_chunk,
-                                     eval_fn, mix_df=ecfg.mix,
-                                     engine_name="nsga2")
+                                     eval_fn, mix_df=ecfg.mix)
 
 
 def frontier_points(state: NSGA2State) -> np.ndarray:

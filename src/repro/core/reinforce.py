@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.core import chunk as chunk_lib
 from repro.core import env as env_lib
 from repro.core import policy as policy_lib
+from repro.core import programs
 from repro.costmodel import maestro
 from repro.training import optim
 
@@ -216,6 +217,32 @@ def init_search(env: env_lib.EnvArrays, ecfg: env_lib.EnvConfig,
         key=key, epoch=jnp.zeros((), jnp.int32))
 
 
+def _search_program(env: env_lib.EnvArrays, ecfg: env_lib.EnvConfig,
+                    pcfg: policy_lib.PolicyConfig, rcfg: ReinforceConfig):
+    """The jitted ``(state, n) -> n-epoch scan`` of one search, from the
+    process-wide program cache (:mod:`repro.core.programs`).
+
+    The key is exactly what the epoch function is built from: ``ecfg``,
+    ``pcfg``, ``rcfg`` with its seed and epoch count zeroed (they reach the
+    program only through its state and the static ``n``, and the builder
+    sees the zeroed copy) and the contents of ``env``.
+    """
+    rcfg = dataclasses.replace(rcfg, epochs=0, seed=0)
+    key = (ecfg, pcfg, rcfg, programs.digest(*env))
+
+    def build():
+        epoch_fn = make_epoch_fn(ecfg, pcfg, rcfg, env,
+                                 optim.Adam(lr=rcfg.lr))
+
+        @functools.partial(jax.jit, static_argnames=("n",))
+        def scan_chunk(state, n):
+            return jax.lax.scan(epoch_fn, state, None, length=n)
+
+        return scan_chunk
+
+    return programs.cache("reinforce").get(key, build)
+
+
 def run_search(workload, ecfg: env_lib.EnvConfig,
                rcfg: ReinforceConfig = ReinforceConfig(),
                pcfg: policy_lib.PolicyConfig | None = None,
@@ -227,7 +254,8 @@ def run_search(workload, ecfg: env_lib.EnvConfig,
     Runs in jitted lax.scan chunks so long searches can checkpoint between
     chunks.  ``on_chunk(state, chunk_history, epochs_done)`` fires after each
     chunk (the unified API streams progress through it); the compiled epoch
-    function is reused across chunks either way.
+    function is reused across chunks, and across searches of the same
+    program key (:mod:`repro.core.programs`).
     """
     env = env_lib.make_env(workload, ecfg)
     if pcfg is None:
@@ -236,11 +264,8 @@ def run_search(workload, ecfg: env_lib.EnvConfig,
     opt = optim.Adam(lr=rcfg.lr)
     if state is None:
         state = init_search(env, ecfg, pcfg, rcfg, opt)
-    epoch_fn = make_epoch_fn(ecfg, pcfg, rcfg, env, opt)
 
-    @functools.partial(jax.jit, static_argnames=("n",))
-    def scan_chunk(state, n):
-        return jax.lax.scan(epoch_fn, state, None, length=n)
+    scan_chunk = _search_program(env, ecfg, pcfg, rcfg)
 
     def run_chunk(state, n):
         state, metrics = scan_chunk(state, n)

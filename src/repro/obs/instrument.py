@@ -47,6 +47,10 @@ JIT_SECONDS = _metrics.histogram(
 JIT_COMPILES = _metrics.counter(
     "repro_jit_compiles", "Backend compiles reported by JAX",
     labels=("result",))   # result: compiled|cache_hit
+ENGINE_PROGRAMS = _metrics.counter(
+    "repro_engine_programs",
+    "Engine program-cache lookups, one per search",
+    labels=("engine", "result"))   # result: reused|built
 DISPATCH_SECONDS = _metrics.histogram(
     "repro_dispatch_seconds", "XLA/Pallas dispatch wall-clock",
     labels=("program",))
