@@ -15,6 +15,7 @@ from typing import List, Optional, Tuple
 class ArchConfig:
     name: str
     family: str                # dense | moe | ssm | hybrid | audio | vlm
+                               # | mla_moe
     num_layers: int
     d_model: int
     num_heads: int
@@ -32,6 +33,25 @@ class ArchConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     moe_capacity_factor: float = 1.25
+
+    # Multi-head latent attention (DeepSeek-V2/V3): queries and keys/values
+    # pass through low-rank latents; each head's query/key is a no-RoPE part
+    # plus a decoupled RoPE part shared across heads.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Fine-grained MoE: always-on shared experts beside the routed ones, the
+    # first ``first_k_dense_replace`` layers dense with a ``dense_d_ff``
+    # (intermediate_size) SwiGLU MLP, and sigmoid routing limited to the
+    # best ``topk_group`` of ``n_group`` expert groups.
+    n_shared_experts: int = 0
+    first_k_dense_replace: int = 0
+    dense_d_ff: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
 
     # SSM (Mamba2 / SSD).
     ssm_state: int = 0
@@ -86,6 +106,21 @@ class ArchConfig:
             mlp = 2 * d * f
         if self.num_experts:
             mlp = self.num_experts * 3 * d * f + d * self.num_experts
+        if self.family == "mla_moe":
+            H = self.num_heads
+            attn = (d * self.q_lora_rank
+                    + self.q_lora_rank * H * (self.qk_nope_head_dim
+                                              + self.qk_rope_head_dim)
+                    + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                    + self.kv_lora_rank * H * (self.qk_nope_head_dim
+                                               + self.v_head_dim)
+                    + H * self.v_head_dim * d)
+            moe = (self.num_experts + self.n_shared_experts) * 3 * d * f \
+                + d * self.num_experts
+            k = self.first_k_dense_replace
+            return int(self.num_layers * attn + k * 3 * d * self.dense_d_ff
+                       + (self.num_layers - k) * moe
+                       + 2 * self.vocab_size * d)
         if self.family == "ssm":
             di = self.ssm_expand * d
             blk = d * (2 * di + 2 * self.ssm_state) + di * d

@@ -14,6 +14,8 @@ from repro.costmodel.layers import (
     CONV,
     DWCONV,
     GEMM,
+    BMM,
+    EXPERTS,
     NUM_FIELDS,
 )
 from repro.costmodel.dataflows import (
@@ -42,6 +44,8 @@ __all__ = [
     "CONV",
     "DWCONV",
     "GEMM",
+    "BMM",
+    "EXPERTS",
     "NUM_FIELDS",
     "DLA",
     "EYE",

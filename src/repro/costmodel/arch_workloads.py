@@ -16,9 +16,31 @@ token positions):
     intra-chunk matmuls as seq x seq GEMMs per chunk.
   * identical consecutive layers collapse into one entry with ``repeat=L``
     so RL episode lengths stay tractable for 90+ layer models (layers.py).
+
+The ``mla_moe`` family (DeepSeek-V3) lowers its decode step only
+(``phase="decode"``): ``batch`` requests, each one new token against its
+own ``ctx``-position latent cache, through the absorbed MLA path decode
+engines run (every head shares one ``kv_lora + rope``-wide cache row):
+
+  q_a, q_b, kv_a, o_proj      GEMM, M = batch, repeat L
+  q_absorb, v_absorb          BMM, one instance per head (X = heads)
+  score, ctx                  BMM, one instance per request (X = batch):
+                              (heads x ctx) against that request's cache
+  dense up/down               GEMM, the first ``first_k_dense_replace`` layers
+  router, shared up/down      GEMM, the other layers
+  exp_up, exp_down            EXPERTS, X = routed experts, each seeing
+                              M = ceil(batch * top_k / experts) tokens
+                              (uniform routing)
+  unembed                     GEMM
+
+Norms, RoPE, softmax and the routing's top-k cost no MACs here (the
+descriptor counts MACs only), and the multi-token-prediction layer is left
+out.  ``repro.costmodel.deepseek_v3_ref`` is the plain forward these rows
+are checked against.
 """
 from __future__ import annotations
 
+import math
 from typing import List
 
 from repro import configs
@@ -89,14 +111,64 @@ def _mamba_layers(cfg: ArchConfig, tokens: int, repeat: int,
     ]
 
 
+def _mla_moe_decode(cfg: ArchConfig, batch: int,
+                    ctx: int) -> List[LayerSpec]:
+    """One decode step of an MLA + fine-grained-MoE model, up to the
+    unembedding (module docstring)."""
+    d, H, L = cfg.d_model, cfg.num_heads, cfg.num_layers
+    nope, rope, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    lat = cfg.kv_lora_rank
+    k_dense = cfg.first_k_dense_replace
+    L_moe = L - k_dense
+    f, E = cfg.d_ff, cfg.num_experts
+    shared = cfg.n_shared_experts * f
+    per_expert = math.ceil(batch * cfg.experts_per_token / E)
+    return [
+        LayerSpec.gemm(batch, cfg.q_lora_rank, d, repeat=L, name="q_a"),
+        LayerSpec.gemm(batch, H * (nope + rope), cfg.q_lora_rank, repeat=L,
+                       name="q_b"),
+        LayerSpec.gemm(batch, lat + rope, d, repeat=L, name="kv_a"),
+        LayerSpec.bmm(batch, lat, nope, H, repeat=L, name="q_absorb"),
+        LayerSpec.bmm(H, ctx, lat + rope, batch, repeat=L, name="score"),
+        LayerSpec.bmm(H, lat, ctx, batch, repeat=L, name="ctx"),
+        LayerSpec.bmm(batch, v, lat, H, repeat=L, name="v_absorb"),
+        LayerSpec.gemm(batch, d, H * v, repeat=L, name="o_proj"),
+        LayerSpec.gemm(batch, 2 * cfg.dense_d_ff, d, repeat=k_dense,
+                       name="dense_up"),
+        LayerSpec.gemm(batch, d, cfg.dense_d_ff, repeat=k_dense,
+                       name="dense_down"),
+        LayerSpec.gemm(batch, E, d, repeat=L_moe, name="router"),
+        LayerSpec.gemm(batch, 2 * shared, d, repeat=L_moe,
+                       name="shared_up"),
+        LayerSpec.gemm(batch, d, shared, repeat=L_moe, name="shared_down"),
+        LayerSpec.experts(per_expert, 2 * f, d, E, repeat=L_moe,
+                          name="exp_up"),
+        LayerSpec.experts(per_expert, d, f, E, repeat=L_moe,
+                          name="exp_down"),
+    ]
+
+
 def lower_arch(name: str, tokens: int = 1024, ctx: int = None,
-               include_unembed: bool = True) -> List[LayerSpec]:
-    """Lower an architecture to its serving workload at ``tokens`` positions.
+               include_unembed: bool = True, phase: str = "prefill",
+               batch: int = 64) -> List[LayerSpec]:
+    """Lower an architecture to its serving workload (see
+    :func:`lower_config`)."""
+    return lower_config(configs.get(name), tokens, ctx, include_unembed,
+                        phase, batch)
+
+
+def lower_config(cfg: ArchConfig, tokens: int = 1024, ctx: int = None,
+                 include_unembed: bool = True, phase: str = "prefill",
+                 batch: int = 64) -> List[LayerSpec]:
+    """Lower a config to its serving workload at ``tokens`` positions.
 
     ctx: attention context length (defaults to tokens -- self-attention over
-    the processed window).
+    the processed window).  ``phase`` and ``batch`` matter to the
+    ``mla_moe`` family alone, which lowers only ``phase="decode"``: one
+    step of ``batch`` requests over ``ctx`` cached positions (``tokens``
+    unused).  Every other family lowers one forward pass over ``tokens``
+    positions whatever the phase.
     """
-    cfg = configs.get(name)
     ctx = ctx or tokens
     out: List[LayerSpec] = []
     fam = cfg.family
@@ -118,6 +190,13 @@ def lower_arch(name: str, tokens: int = 1024, ctx: int = None,
         out += _attn_layers(cfg, tokens, ctx, L, "dec.self")
         out += _attn_layers(cfg, tokens, Se, L, "dec.cross")
         out += _ffn_layers(cfg, tokens, L, "dec")
+    elif fam == "mla_moe":
+        if phase != "decode":
+            raise ValueError(
+                f"{cfg.name}: the mla_moe family lowers only its decode step "
+                f"(phase='decode'), not phase={phase!r}")
+        out += _mla_moe_decode(cfg, batch, ctx)
+        tokens = batch          # the step's new tokens, one a request
     elif fam == "vlm":
         n_cross = L // cfg.cross_attn_period
         n_self = L - n_cross

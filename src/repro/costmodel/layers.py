@@ -15,14 +15,30 @@ A layer is described exactly as in the paper's observation space (SIII-B):
                   Y  = M   (tokens / rows  ~ activation rows), X = 1
                   R  = S = 1
               so Y' = M, X' = 1 and total MACs = M*N*Kg.
+  * BMM     : ``instances`` independent (M, N, Kg) matmuls that run IN TURN
+              on one partition -- per-head absorbed projections, or each
+              decode request's score / context against its own KV cache.
+  * EXPERTS : ``instances`` independent (M, N, Kg) matmuls that run SIDE BY
+              SIDE, one partition each -- the routed experts of an MoE
+              layer, each seeing its own M tokens.
+              Both keep the GEMM mapping (K = N, C = Kg, Y = M, R = S = 1)
+              and carry the instance count in X.
 
-We additionally carry a ``repeat`` field: the number of *identical* hardware
-instances of this layer (e.g. the E experts of an MoE block, or consecutive
-identical transformer blocks).  One RL action covers the whole group; latency,
-energy, area and power scale by ``repeat`` (each instance receives the same
-(PE, Buf) assignment -- this keeps episode lengths tractable for 90+ layer
-LLMs while remaining faithful to the paper's per-layer formulation, where
-every group member *is* the same layer shape).
+Three multiplicities, one per field:
+
+  * ``repeat`` (every type): identical consecutive layers, each its own
+    partition with the same (PE, Buf) assignment (e.g. the L blocks of a
+    transformer).  Latency, energy, area and power all scale by it.  One
+    RL action covers the whole group, which keeps episode lengths tractable
+    for 90+ layer LLMs while staying faithful to the paper's per-layer
+    formulation, where every group member *is* the same layer shape.
+  * time instances (BMM, ``X``): X operands stream through one partition
+    in turn.  Latency and energy scale by X; area and power do not.
+  * side-by-side instances (EXPERTS, ``X``): X partitions run at once.
+    Area, power and energy scale by X; latency does not.
+
+For BMM and EXPERTS the dataflow terms see one instance (X = 1); see
+``maestro._gated_cost``.
 """
 from __future__ import annotations
 
@@ -34,6 +50,9 @@ import numpy as np
 CONV = 0
 DWCONV = 1
 GEMM = 2
+BMM = 3
+EXPERTS = 4
+TYPE_NAMES = ("conv", "dwconv", "gemm", "bmm", "experts")   # by type id
 
 # Descriptor array column layout.
 F_K, F_C, F_Y, F_X, F_R, F_S, F_TYPE, F_REPEAT = range(8)
@@ -71,7 +90,23 @@ class LayerSpec:
         """(M,Kg) x (Kg,N): K=N, C=Kg, Y=M, X=1, R=S=1."""
         return LayerSpec(N, Kg, M, 1, 1, 1, GEMM, repeat, name)
 
+    @staticmethod
+    def bmm(M: int, N: int, Kg: int, instances: int, *, repeat: int = 1,
+            name: str = "") -> "LayerSpec":
+        """``instances`` (M,Kg) x (Kg,N) matmuls in turn on one partition:
+        K=N, C=Kg, Y=M, X=instances, R=S=1."""
+        return LayerSpec(N, Kg, M, instances, 1, 1, BMM, repeat, name)
+
+    @staticmethod
+    def experts(M: int, N: int, Kg: int, instances: int, *, repeat: int = 1,
+                name: str = "") -> "LayerSpec":
+        """``instances`` (M,Kg) x (Kg,N) matmuls side by side, one partition
+        each: K=N, C=Kg, Y=M, X=instances, R=S=1."""
+        return LayerSpec(N, Kg, M, instances, 1, 1, EXPERTS, repeat, name)
+
     def macs(self) -> int:
+        if self.type in (BMM, EXPERTS):
+            return self.K * self.C * self.Y * self.X * self.repeat
         yp = max(self.Y - self.R + 1, 1)
         xp = max(self.X - self.S + 1, 1)
         if self.type == DWCONV:

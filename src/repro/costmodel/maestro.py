@@ -57,6 +57,21 @@ Energy   = MAC + L1 + L2 + DRAM access energy + leakage(pe,L1)*latency.
 Area/Power = linear models over PEs, L1 bytes, L2 bytes (=2*pe*L1: the
            double-buffered next tile, exactly how the paper sizes L2), NoC.
 
+Multiplicities (layers.py): ``repeat`` scales every output (identical
+consecutive layers, one partition each).  BMM and EXPERTS rows carry an
+instance count in X; the dataflow terms run on ONE instance (X = 1), then
+
+                 latency     energy      area, power   macs
+  types 0-2      x repeat    x repeat    x repeat      x repeat
+  BMM            x rep * X   x rep * X   x repeat      x rep * X
+  EXPERTS        x repeat    x rep * X   x rep * X     x rep * X
+
+BMM instances run in turn on one partition (each request's score against
+its own KV cache); EXPERTS instances side by side, one partition each (the
+routed experts of an MoE layer).  ``l1_bytes``, ``l2_bytes`` and ``util``
+are per instance.  For types 0-2 the instance factors are exactly 1.0, so
+their values are bit-identical to the model without instance types.
+
 Absolute numbers are NOT calibrated against the MAESTRO binary (DESIGN.md S5)
 -- the paper's claims we reproduce are *relative* search-quality /
 sample-efficiency comparisons, which depend on the landscape structure, not
@@ -99,6 +114,8 @@ from repro.costmodel.dataflows import (
     l1_bytes_formula,
 )
 from repro.costmodel.layers import (
+    BMM,
+    EXPERTS,
     F_C,
     F_K,
     F_R,
@@ -230,17 +247,35 @@ def _dataflow_terms(df_is, is_dw, K_out, C_red, Yp, Xp, R, S, pe, kt,
     return comp, l2, passes_w, passes_a
 
 
-def _gated_cost(K, C, Y, X, R, S, repeat, pe, kt, df_w, is_dw, l1_bytes,
-                prims):
+def _instance_gates(ltype):
+    """(is_bmm, is_experts) as exact 0/1 float32 on both paths.
+
+    The layer type is data, not a design variable, so these gates need no
+    gradient; the soft ``eq_gate`` would leak about 4.5e-5 of BMM into every
+    GEMM row at ``tau = 1`` and move types 0-2 off their values."""
+    gate = HARD.eq_gate
+    return gate(ltype, BMM), gate(ltype, EXPERTS)
+
+
+def _gated_cost(K, C, Y, X, R, S, repeat, pe, kt, df_w, is_dw, inst,
+                l1_bytes, prims):
     """The shared model body below the gates: one set of dataflow-term math.
 
     ``df_w = (w_dla, w_eye, w_shi)`` are style weights (exact one-hots on the
     hard path, a simplex on the soft path); ``is_dw`` the depthwise gate;
-    ``l1_bytes`` the style-selected L1 size (nested-``where`` hard, weighted
-    blend soft).  Every plateau op routes through ``prims``; data-side shape
-    arithmetic (Yp/Xp/macs/traffic volumes) is smooth already and stays
-    shared verbatim.
+    ``inst = (is_bmm, is_experts)`` the exact instance-type gates (see the
+    module docstring's multiplicity table); ``l1_bytes`` the style-selected
+    L1 size (nested-``where`` hard, weighted blend soft).  Every plateau op
+    routes through ``prims``; data-side shape arithmetic (Yp/Xp/macs/traffic
+    volumes) is smooth already and stays shared verbatim.
     """
+    is_bmm, is_exp = inst
+    is_inst = is_bmm + is_exp
+    n_inst = X
+    X = prims.blend(is_inst, 1.0, X)         # dataflow terms: one instance
+    n_lat = prims.blend(is_bmm, n_inst, 1.0)  # exactly 1.0 for types 0-2
+    n_en = prims.blend(is_inst, n_inst, 1.0)
+    n_hw = prims.blend(is_exp, n_inst, 1.0)
     Yp = jnp.maximum(Y - R + 1.0, 1.0)
     Xp = jnp.maximum(X - S + 1.0, 1.0)
     C_red = prims.blend(is_dw, 1.0, C)       # reduction channels
@@ -283,13 +318,13 @@ def _gated_cost(K, C, Y, X, R, S, repeat, pe, kt, df_w, is_dw, l1_bytes,
              + P_L2_MW_B * l2_bytes + P_NOC_MW_PE * pe)
 
     return CostOut(
-        latency=lat * repeat,
-        energy=(energy_pj * repeat) * 1e-3,  # pJ -> nJ
-        area=area * repeat,
-        power=power * repeat,
+        latency=(lat * repeat) * n_lat,
+        energy=((energy_pj * repeat) * 1e-3) * n_en,  # pJ -> nJ
+        area=(area * repeat) * n_hw,
+        power=(power * repeat) * n_hw,
         l1_bytes=l1_bytes,
         l2_bytes=l2_bytes,
-        macs=macs * repeat,
+        macs=(macs * repeat) * n_en,
         util=macs / prims.maximum(comp * pe, 1.0),
     )
 
@@ -310,7 +345,7 @@ def core_cost(K, C, Y, X, R, S, ltype, repeat, pe, kt, df):
     is_dw = gate(ltype, DWCONV)
     l1_bytes = l1_bytes_formula(df, kt, R, S)
     return _gated_cost(K, C, Y, X, R, S, repeat, pe, kt, df_w, is_dw,
-                       l1_bytes, HARD)
+                       _instance_gates(ltype), l1_bytes, HARD)
 
 
 def soft_core_cost(K, C, Y, X, R, S, ltype, repeat, pe, kt, df_weights, tau):
@@ -332,7 +367,7 @@ def soft_core_cost(K, C, Y, X, R, S, ltype, repeat, pe, kt, df_weights, tau):
     dla_b, eye_b, shi_b = l1_bytes_by_style(kt, R, S)
     l1_bytes = df_w[0] * dla_b + df_w[1] * eye_b + df_w[2] * shi_b
     return _gated_cost(K, C, Y, X, R, S, repeat, pe, kt, df_w, is_dw,
-                       l1_bytes, prims)
+                       _instance_gates(ltype), l1_bytes, prims)
 
 
 def evaluate(layers, pe, kt, dataflow):
@@ -347,7 +382,8 @@ def evaluate(layers, pe, kt, dataflow):
     Returns CostOut of broadcast shape; all values are per-layer *including*
     the ``repeat`` multiplicity (latency/energy/area/power all scale by it:
     repeated identical layers are separate pipeline partitions with tied
-    assignments -- see layers.py).
+    assignments -- see layers.py) and a BMM / EXPERTS row's instances (the
+    module docstring's table).
     """
     layers = jnp.asarray(layers)
     f = lambda i: layers[..., i].astype(jnp.float32)

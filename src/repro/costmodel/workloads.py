@@ -209,11 +209,24 @@ def multi_dnn(names: List[str] = None, tokens: int = 32) -> List[LayerSpec]:
     return out
 
 
+def deepseek_v3_decode(batch: int = 64, ctx: int = 32768) -> List[LayerSpec]:
+    """DeepSeek-V3's decode step: ``batch`` requests against ``ctx``-position
+    latent caches, absorbed MLA and 256 routed experts (16 rows; see
+    ``arch_workloads``)."""
+    from repro.costmodel import arch_workloads
+
+    return arch_workloads.lower_arch("deepseek_v3", phase="decode",
+                                     batch=batch, ctx=ctx)
+
+
+
 def get_workload(name: str, **kwargs) -> List[LayerSpec]:
     """Look up a workload by name (paper models + assigned architectures +
-    the ``multi_dnn`` co-design mix)."""
+    DeepSeek-V3's decode step + the ``multi_dnn`` co-design mix)."""
     if name in _PAPER_WORKLOADS:
         return _PAPER_WORKLOADS[name](**kwargs)
+    if name == "deepseek_v3_decode":
+        return deepseek_v3_decode(**kwargs)
     if name == "multi_dnn":
         return multi_dnn(**kwargs)
     # Assigned architectures are lowered from their configs.
@@ -225,4 +238,5 @@ def get_workload(name: str, **kwargs) -> List[LayerSpec]:
 def workload_names() -> List[str]:
     from repro.costmodel import arch_workloads
 
-    return sorted(_PAPER_WORKLOADS) + arch_workloads.arch_names()
+    return (sorted(_PAPER_WORKLOADS) + arch_workloads.arch_names()
+            + ["deepseek_v3_decode"])

@@ -60,6 +60,10 @@ BATCHER_DISPATCHES = _metrics.counter(
 BATCHER_POINTS = _metrics.counter(
     "repro_batcher_points", "Per-layer points through the batcher",
     labels=("kind",))   # kind: submitted|unique|fresh
+BATCHER_FRESH_POINTS = _metrics.counter(
+    "repro_batcher_fresh_points",
+    "Fresh points handed to the cost evaluator, by layer type",
+    labels=("ltype",))   # ltype: conv|dwconv|gemm|bmm|experts
 BATCHER_QUEUE_DEPTH = _metrics.gauge(
     "repro_batcher_queue_depth", "Eval requests awaiting dispatch")
 BATCHER_FUSE_WIDTH = _metrics.histogram(

@@ -39,7 +39,7 @@ import numpy as np
 
 from repro.core import env as env_lib
 from repro.costmodel import maestro
-from repro.costmodel.layers import NUM_FIELDS
+from repro.costmodel.layers import F_TYPE, NUM_FIELDS, TYPE_NAMES
 from repro.obs import instrument as obs_instrument
 from repro.obs import recorder as obs_recorder
 from repro.obs import state as obs_state
@@ -301,10 +301,12 @@ class CostEvalBatcher:
             with _phase("lookup"):
                 values, miss_index = self.cache.get_many(keys)
             t_eval = 0.0
+            fresh_rows = None
             if miss_index:
                 with _phase("eval"):
                     te = time.perf_counter() if obs_state.enabled else 0.0
-                    fresh = self._eval_points(uniq[miss_index])
+                    fresh_rows = uniq[miss_index]
+                    fresh = self._eval_points(fresh_rows)
                     if obs_state.enabled:
                         t_eval = time.perf_counter() - te
             with _phase("fill"):
@@ -323,7 +325,8 @@ class CostEvalBatcher:
                 # repro_batcher_dispatch_seconds ends here, before the
                 # aggregation (batcher.aggregate times that part).
                 self._record_dispatch(items, t0, time.perf_counter() - t0,
-                                      t_eval, len(uniq), miss_index, inv)
+                                      t_eval, len(uniq), miss_index, inv,
+                                      fresh_rows)
 
             with self._stats_lock:
                 s = self._stats
@@ -350,7 +353,8 @@ class CostEvalBatcher:
                     it.event.set()
 
     def _record_dispatch(self, items: List[_Item], t0: float, dt: float,
-                         t_eval: float, n_uniq: int, miss_index, inv) -> None:
+                         t_eval: float, n_uniq: int, miss_index, inv,
+                         fresh_rows) -> None:
         """Telemetry for one finished dispatch: process-wide metrics plus
         per-item flight-recorder attribution (each rider is credited its own
         share of the fused batch, including its own cached-vs-fresh split).
@@ -366,6 +370,13 @@ class CostEvalBatcher:
         obs_instrument.BATCHER_POINTS.inc(n_points, kind="submitted")
         obs_instrument.BATCHER_POINTS.inc(n_uniq, kind="unique")
         obs_instrument.BATCHER_POINTS.inc(len(miss_index), kind="fresh")
+        if fresh_rows is not None:
+            by_type = np.bincount(fresh_rows[:, F_TYPE].astype(np.int64),
+                                  minlength=len(TYPE_NAMES))
+            for ltype, n in zip(TYPE_NAMES, by_type):
+                if n:
+                    obs_instrument.BATCHER_FRESH_POINTS.inc(int(n),
+                                                            ltype=ltype)
         obs_instrument.BATCHER_FUSE_WIDTH.observe(len(items))
         obs_instrument.BATCHER_DISPATCH_SECONDS.observe(dt)
         fresh_pp = None
