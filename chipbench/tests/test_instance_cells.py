@@ -50,6 +50,7 @@ def test_new_cells_have_their_files():
         assert drivers.load(mix["driver"])
         assert limits["best_gap"] == 1e-5
     assert run.load_cell(LLM)[4]["point_gap"] == 1e-5
+    assert run.load_cell(FANOUT)[4]["rl_value_gap"] == 1e-5
     assert run.load_cell(FANOUT)[1]["chips"] == 4
     assert callable(run.load_metric(METRIC))
 
@@ -123,6 +124,21 @@ def test_fanout_marks_sum_the_shards():
     for shard, step in ((0, 200), (1, 200), (0, 400), (2, 200), (1, 400)):
         req.on_progress(Trial(step, 1.0, 1.0, shard=shard))
     assert seen == [200, 400, 600, 800, 1000]
+
+
+def test_fanout_answer_without_shard_histories_is_not_correct():
+    """An answer whose shards' epochs were not kept cannot be replayed, so
+    it reads as an infinite gap rather than passing unchecked."""
+    from chipbench.drivers.fanout_closed import FanoutClosed
+    ctx = types.SimpleNamespace(config=_config("mobilenet_v2-iot"),
+                                mix={"progress_every": 200})
+    drv = FanoutClosed(ctx)
+    assert drv.rl_value_gap(False) == float("inf")
+    spec = {"objective": "latency", "platform": "iot", "dataflow": "dla",
+            "seed": 3}
+    drv.outcomes.append(drivers.Outcome(spec, 1.0, [1], [1], [0]))
+    drv.shard_histories[4] = [{"mean_value": [1.0]}]
+    assert drv.rl_value_gap(False) == float("inf")
 
 
 def _snap(fresh):

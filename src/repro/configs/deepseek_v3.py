@@ -4,8 +4,9 @@
 (hf:deepseek-ai/DeepSeek-V3 config.json; the one MTP layer is left out).
 
 Not in ``ARCH_IDS``: the LM runtime does not build MLA, so this config is
-lowered only for the cost model (``arch_workloads.lower_arch(...,
-phase="decode")``, workload ``deepseek_v3_decode``)."""
+lowered only for the cost model, as one decode step
+(``arch_workloads.lower_arch(name, tokens=64, ctx=32768)``, workload
+``deepseek_v3_decode``)."""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(

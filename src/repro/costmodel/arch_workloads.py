@@ -17,19 +17,19 @@ token positions):
   * identical consecutive layers collapse into one entry with ``repeat=L``
     so RL episode lengths stay tractable for 90+ layer models (layers.py).
 
-The ``mla_moe`` family (DeepSeek-V3) lowers its decode step only
-(``phase="decode"``): ``batch`` requests, each one new token against its
-own ``ctx``-position latent cache, through the absorbed MLA path decode
-engines run (every head shares one ``kv_lora + rope``-wide cache row):
+The ``mla_moe`` family (DeepSeek-V3) always lowers one decode step:
+``tokens`` requests, each one new token against its own ``ctx``-position
+latent cache, through the absorbed MLA path decode engines run (every head
+shares one ``kv_lora + rope``-wide cache row):
 
-  q_a, q_b, kv_a, o_proj      GEMM, M = batch, repeat L
+  q_a, q_b, kv_a, o_proj      GEMM, M = tokens, repeat L
   q_absorb, v_absorb          BMM, one instance per head (X = heads)
-  score, ctx                  BMM, one instance per request (X = batch):
+  score, ctx                  BMM, one instance per request (X = tokens):
                               (heads x ctx) against that request's cache
   dense up/down               GEMM, the first ``first_k_dense_replace`` layers
   router, shared up/down      GEMM, the other layers
   exp_up, exp_down            EXPERTS, X = routed experts, each seeing
-                              M = ceil(batch * top_k / experts) tokens
+                              M = ceil(tokens * top_k / experts) tokens
                               (uniform routing)
   unembed                     GEMM
 
@@ -149,25 +149,19 @@ def _mla_moe_decode(cfg: ArchConfig, batch: int,
 
 
 def lower_arch(name: str, tokens: int = 1024, ctx: int = None,
-               include_unembed: bool = True, phase: str = "prefill",
-               batch: int = 64) -> List[LayerSpec]:
+               include_unembed: bool = True) -> List[LayerSpec]:
     """Lower an architecture to its serving workload (see
     :func:`lower_config`)."""
-    return lower_config(configs.get(name), tokens, ctx, include_unembed,
-                        phase, batch)
+    return lower_config(configs.get(name), tokens, ctx, include_unembed)
 
 
 def lower_config(cfg: ArchConfig, tokens: int = 1024, ctx: int = None,
-                 include_unembed: bool = True, phase: str = "prefill",
-                 batch: int = 64) -> List[LayerSpec]:
+                 include_unembed: bool = True) -> List[LayerSpec]:
     """Lower a config to its serving workload at ``tokens`` positions.
 
     ctx: attention context length (defaults to tokens -- self-attention over
-    the processed window).  ``phase`` and ``batch`` matter to the
-    ``mla_moe`` family alone, which lowers only ``phase="decode"``: one
-    step of ``batch`` requests over ``ctx`` cached positions (``tokens``
-    unused).  Every other family lowers one forward pass over ``tokens``
-    positions whatever the phase.
+    the processed window).  The ``mla_moe`` family lowers one decode step
+    instead: ``tokens`` requests, each against ``ctx`` cached positions.
     """
     ctx = ctx or tokens
     out: List[LayerSpec] = []
@@ -191,12 +185,7 @@ def lower_config(cfg: ArchConfig, tokens: int = 1024, ctx: int = None,
         out += _attn_layers(cfg, tokens, Se, L, "dec.cross")
         out += _ffn_layers(cfg, tokens, L, "dec")
     elif fam == "mla_moe":
-        if phase != "decode":
-            raise ValueError(
-                f"{cfg.name}: the mla_moe family lowers only its decode step "
-                f"(phase='decode'), not phase={phase!r}")
-        out += _mla_moe_decode(cfg, batch, ctx)
-        tokens = batch          # the step's new tokens, one a request
+        out += _mla_moe_decode(cfg, tokens, ctx)
     elif fam == "vlm":
         n_cross = L // cfg.cross_attn_period
         n_self = L - n_cross

@@ -167,13 +167,24 @@ def ncf(batch: int = 1024, embed: int = 128) -> List[LayerSpec]:
     return layers
 
 
-_PAPER_WORKLOADS: Dict[str, Callable[..., List[LayerSpec]]] = {
+def deepseek_v3_decode(tokens: int = 64,
+                       ctx: int = 32768) -> List[LayerSpec]:
+    """DeepSeek-V3's decode step: ``tokens`` requests against
+    ``ctx``-position latent caches, absorbed MLA and 256 routed experts (16
+    rows; see ``arch_workloads``)."""
+    from repro.costmodel import arch_workloads
+
+    return arch_workloads.lower_arch("deepseek_v3", tokens=tokens, ctx=ctx)
+
+
+_NAMED_WORKLOADS: Dict[str, Callable[..., List[LayerSpec]]] = {
     "mobilenet_v2": mobilenet_v2,
     "resnet50": resnet50,
     "mnasnet": mnasnet,
     "gnmt": gnmt,
     "transformer": transformer,
     "ncf": ncf,
+    "deepseek_v3_decode": deepseek_v3_decode,
 }
 
 
@@ -200,8 +211,8 @@ def multi_dnn(names: List[str] = None, tokens: int = 32) -> List[LayerSpec]:
 
     out: List[LayerSpec] = []
     for n in names:
-        if n in _PAPER_WORKLOADS:
-            layers = _PAPER_WORKLOADS[n]()
+        if n in _NAMED_WORKLOADS:
+            layers = _NAMED_WORKLOADS[n]()
         else:
             layers = arch_workloads.lower_arch(n, tokens=tokens)
         out.extend(dataclasses.replace(l, name=f"{n}.{l.name}")
@@ -209,24 +220,11 @@ def multi_dnn(names: List[str] = None, tokens: int = 32) -> List[LayerSpec]:
     return out
 
 
-def deepseek_v3_decode(batch: int = 64, ctx: int = 32768) -> List[LayerSpec]:
-    """DeepSeek-V3's decode step: ``batch`` requests against ``ctx``-position
-    latent caches, absorbed MLA and 256 routed experts (16 rows; see
-    ``arch_workloads``)."""
-    from repro.costmodel import arch_workloads
-
-    return arch_workloads.lower_arch("deepseek_v3", phase="decode",
-                                     batch=batch, ctx=ctx)
-
-
-
 def get_workload(name: str, **kwargs) -> List[LayerSpec]:
-    """Look up a workload by name (paper models + assigned architectures +
-    DeepSeek-V3's decode step + the ``multi_dnn`` co-design mix)."""
-    if name in _PAPER_WORKLOADS:
-        return _PAPER_WORKLOADS[name](**kwargs)
-    if name == "deepseek_v3_decode":
-        return deepseek_v3_decode(**kwargs)
+    """Look up a workload by name (paper models and DeepSeek-V3's decode
+    step + assigned architectures + the ``multi_dnn`` co-design mix)."""
+    if name in _NAMED_WORKLOADS:
+        return _NAMED_WORKLOADS[name](**kwargs)
     if name == "multi_dnn":
         return multi_dnn(**kwargs)
     # Assigned architectures are lowered from their configs.
@@ -238,5 +236,4 @@ def get_workload(name: str, **kwargs) -> List[LayerSpec]:
 def workload_names() -> List[str]:
     from repro.costmodel import arch_workloads
 
-    return (sorted(_PAPER_WORKLOADS) + arch_workloads.arch_names()
-            + ["deepseek_v3_decode"])
+    return sorted(_NAMED_WORKLOADS) + arch_workloads.arch_names()

@@ -168,8 +168,11 @@ def test_decode_workload_is_registered_and_prefill_refused():
     assert len(wl) == 16
     assert [l.type for l in wl].count(BMM) == 4
     assert [l.type for l in wl].count(EXPERTS) == 2
-    with pytest.raises(ValueError, match="decode"):
-        arch_workloads.lower_arch("deepseek_v3")
+    # mla_moe lowers no prefill: asked for tokens, it lowers a decode step
+    # of that many requests, each scoring its own cache (X = tokens).
+    score = {l.name: l for l in arch_workloads.lower_arch(
+        "deepseek_v3", tokens=8, ctx=100)}["score"]
+    assert (score.type, score.X, score.K) == (BMM, 8, 100)
     req = api.SearchRequest(workload="deepseek_v3_decode")
     assert req.num_layers == 16
 
@@ -177,8 +180,7 @@ def test_decode_workload_is_registered_and_prefill_refused():
 def _lowered(cfg, batch, ctx):
     kind = {GEMM: "gemm", BMM: "bmm", EXPERTS: "experts"}
     return [(l.name, l.Y, l.K, l.C, l.X, kind[l.type]) for l in
-            arch_workloads.lower_config(cfg, phase="decode", batch=batch,
-                                        ctx=ctx)]
+            arch_workloads.lower_config(cfg, tokens=batch, ctx=ctx)]
 
 
 def test_absorbed_mla_equals_naive_and_matmuls_are_the_rows():
@@ -219,7 +221,7 @@ def test_published_matmuls_are_the_rows_by_shape():
     assert [tuple(r) for r in seen[0]] == _lowered(cfg, B, T)
     assert layers_to_array(workloads.get_workload("deepseek_v3_decode")) \
         .tolist() == layers_to_array(arch_workloads.lower_config(
-            cfg, phase="decode", batch=B, ctx=T)).tolist()
+            cfg, tokens=B, ctx=T)).tolist()
 
 
 def test_chipbench_config_holds_the_workload():
