@@ -113,7 +113,7 @@ SPAN_NAMES = (
     "search.step",        # one host-evaluated generation (engine)
     "search.eval",        # its wait on the injected eval_fn (engine)
     "batcher.dispatch",   # one fused dispatch (items, points, unique, fresh)
-    "batcher.dedup",      # concat + np.unique + per-row keys
+    "batcher.dedup",      # concat + dedup by row bytes + keys
     "batcher.lookup",     # memo-cache get_many
     "batcher.eval",       # the fresh points' evaluation
     "batcher.fill",       # memo-cache put_many + per-point stack

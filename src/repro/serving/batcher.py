@@ -8,8 +8,8 @@ funneled through one dispatcher thread that:
   1. flattens every pending request's genomes into per-layer *points*
      ``(layer fields, pe, kt, df)`` -- the cost model is per-point, so points
      from different workloads concatenate freely (multi-tenant batching);
-  2. dedupes identical points across (and within) requests with one
-     ``np.unique`` pass;
+  2. dedupes identical points across (and within) requests by their bytes
+     (:func:`dedup_point_rows`), the memo cache's own identity;
   3. consults the :class:`~repro.serving.cost_cache.CostMemoCache` and
      evaluates only the genuinely new points in ONE fused call -- the Pallas
      per-row-layers kernel (``ops.batched_cost_multi``) on TPU, the jitted
@@ -31,7 +31,7 @@ from __future__ import annotations
 import functools
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -296,8 +296,7 @@ class CostEvalBatcher:
                 rows = (items[0].points if len(items) == 1
                         else np.concatenate([it.points for it in items],
                                             axis=0))
-                uniq, inv = np.unique(rows, axis=0, return_inverse=True)
-                keys = [u.tobytes() for u in uniq]
+                uniq, inv, keys = dedup_point_rows(rows)
             with _phase("lookup"):
                 values, miss_index = self.cache.get_many(keys)
             t_eval = 0.0
@@ -381,7 +380,6 @@ class CostEvalBatcher:
         obs_instrument.BATCHER_DISPATCH_SECONDS.observe(dt)
         fresh_pp = None
         if any(it.recorder is not None for it in items):
-            inv = np.asarray(inv).ravel()
             first = np.full(n_uniq, len(inv), dtype=np.int64)
             np.minimum.at(first, inv, np.arange(len(inv)))
             fresh_pp = np.zeros(len(inv), bool)   # per submitted point
@@ -446,6 +444,27 @@ def eval_point_rows(rows: np.ndarray, use_kernel: bool) -> np.ndarray:
                          rp[:, _KT_COL], rp[:, _DF_COL])
         out = np.asarray(out)
     return out[:M]
+
+
+_ROW_VOID = np.dtype((np.void, 4 * ROW_WIDTH))
+
+
+def dedup_point_rows(rows: np.ndarray
+                     ) -> Tuple[np.ndarray, np.ndarray, List[bytes]]:
+    """(P, ROW_WIDTH) f32 rows -> ``(uniq, inv, keys)`` with
+    ``uniq[inv] == rows`` and ``keys[i] == uniq[i].tobytes()``.
+
+    Identity is the row's own bytes -- the memo cache's key -- so each row
+    is one fixed-width void scalar and one 1-D ``np.unique`` sorts them by
+    ``memcmp``; a float ``np.unique(axis=0)`` compares field by field, at
+    several times the cost.  For the rows :func:`pack_point_rows`
+    makes (finite, never ``-0.0`` or NaN) byte identity is float equality.
+    Unique rows come out in byte order.
+    """
+    v = np.ascontiguousarray(rows, np.float32).view(_ROW_VOID).ravel()
+    uv, inv = np.unique(v, return_inverse=True)
+    return (uv.view(np.float32).reshape(-1, ROW_WIDTH), inv.ravel(),
+            uv.tolist())
 
 
 def pack_point_rows(layers: np.ndarray, pe, kt, df) -> np.ndarray:
