@@ -19,6 +19,7 @@ from repro.core import env as env_lib, reinforce
 from repro.costmodel import workloads
 from repro.costmodel.layers import layers_to_array
 from repro.kernels import ops as kops
+from repro.kernels import ref as kref
 
 
 def _bench(fn, *args, iters=5):
@@ -42,16 +43,16 @@ def run(budget_name: str = "quick") -> dict:
         pe = jax.random.uniform(key, (B, N), minval=1.0, maxval=128.0)
         kt = jax.random.uniform(key, (B, N), minval=1.0, maxval=12.0)
 
-        ref_fn = jax.jit(lambda l, p, k: kops.batched_cost(
-            l, p, k, 0.0, use_kernel=False))
+        ref_fn = jax.jit(lambda l, p, k: kref.cost_eval_ref(
+            l.T, p, k, jnp.zeros_like(p)))
         t_ref = _bench(ref_fn, layers, pe, kt)
         evals = B * N
         rows.append([f"oracle (jnp)", B, f"{evals/t_ref:,.0f}"])
         payload[f"oracle_B{B}_evals_per_s"] = evals / t_ref
 
         if B <= 2048:  # interpret mode is python-speed; keep it bounded
-            kern_fn = jax.jit(lambda l, p, k: kops.batched_cost(
-                l, p, k, 0.0, use_kernel=True))
+            kern_fn = jax.jit(lambda l, p, k: kops._batched_cost_pallas(
+                l, p, k, 0.0))
             t_k = _bench(kern_fn, layers, pe, kt, iters=2)
             rows.append([f"pallas (interpret)", B, f"{evals/t_k:,.0f}"])
             payload[f"pallas_interp_B{B}_evals_per_s"] = evals / t_k

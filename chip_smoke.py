@@ -37,8 +37,8 @@ clock), persistent-cache hits, and what it checked.
 
 The last line of standard output is {"ok": true, "device": {...}}; any
 failed check exits non-zero without it.  --rehearse runs the phases on any
-platform (the CPU, with interpret-mode kernels), skips the checks only a
-TPU can pass, and never prints the ok line.
+platform (on the CPU the ops take their kernels/ref.py oracles), skips the
+checks only a TPU can pass, and never prints the ok line.
 """
 from __future__ import annotations
 

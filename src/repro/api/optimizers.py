@@ -35,8 +35,7 @@ def _policy_config(ecfg: env_lib.EnvConfig, opts) -> policy_lib.PolicyConfig:
     return policy_lib.PolicyConfig(
         obs_dim=ecfg.obs_dim, mix=ecfg.mix, levels=ecfg.levels,
         hidden=pol.get("hidden", policy_lib.HIDDEN),
-        kind=pol.get("kind", "rnn"),
-        use_kernel=pol.get("use_kernel"))
+        kind=pol.get("kind", "rnn"))
 
 
 # ---------------------------------------------------------------------------
@@ -138,7 +137,7 @@ def _ga_cfg(request: SearchRequest) -> ga_lib.GAConfig:
         population=pop, generations=gens,
         mutation_rate=opts.get("mutation_rate", 0.05),
         crossover_rate=opts.get("crossover_rate", 0.05),
-        seed=request.seed, use_kernel=opts.get("use_kernel"))
+        seed=request.seed)
 
 
 @register("ga")
@@ -190,7 +189,7 @@ def _nsga2_cfg(request: SearchRequest) -> nsga2_lib.NSGA2Config:
         mutation_rate=opts.get("mutation_rate", 0.05),
         crossover_rate=opts.get("crossover_rate", 0.5),
         archive=int(opts.get("archive", 128)),
-        seed=request.seed, use_kernel=opts.get("use_kernel"))
+        seed=request.seed)
 
 
 @register("nsga2", aliases=("pareto", "moo"))
@@ -237,8 +236,7 @@ class NSGA2Optimizer:
             # and drifts an ulp on some workloads).
             from repro.serving import batcher as batcher_lib
 
-            eval_fn = batcher_lib.make_local_costs_eval(
-                env, request.env, use_kernel=cfg.use_kernel)
+            eval_fn = batcher_lib.make_local_costs_eval(env, request.env)
         state, hist = nsga2_lib.run_nsga2_search(
             wl, request.env, cfg, chunk=chunk, on_chunk=on_chunk,
             eval_fn=eval_fn, env=env)

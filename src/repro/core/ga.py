@@ -41,6 +41,7 @@ from repro.core import env as env_lib
 from repro.core import programs
 from repro.obs import instrument as obs_instrument
 from repro.costmodel import dataflows as dfl
+from repro.kernels import ops as kops
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,9 +51,6 @@ class GAConfig:
     mutation_rate: float = 0.05
     crossover_rate: float = 0.05
     seed: int = 0
-    # None = auto: the Pallas batched cost kernel on TPU, the jnp oracle
-    # elsewhere (interpret mode would dominate the generation on CPU).
-    use_kernel: Optional[bool] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,16 +98,19 @@ class GAResult(NamedTuple):
     evals: int
 
 
-def _fitness(env, ecfg, pe, kt, df, use_kernel: bool = False):
-    if use_kernel and getattr(pe, "ndim", 0) == 2:
-        # Population-sized batches are exactly the Pallas kernel's shape:
-        # (B, N) design points against the (N, NUM_FIELDS) workload.
-        from repro.kernels import ops
-        lat, en, area, pw = ops.batched_cost(env.layers, pe, kt, df)
+def _fitness(env, ecfg, pe, kt, df):
+    # On a TPU a population is the Pallas kernel's shape: (B, N) design
+    # points against the (N, NUM_FIELDS) workload.
+    if kops.on_tpu() and getattr(pe, "ndim", 0) == 2:
+        lat, en, area, pw = kops.batched_cost(env.layers, pe, kt, df)
         perf, _, feas = env_lib.aggregate_costs(lat, en, area, pw, ecfg,
                                                 env.budget)
         return jnp.where(feas, perf, jnp.inf)
-    perf, cons, feas = env_lib.genome_cost(env, ecfg, pe, kt, df)
+    return _oracle_fitness(env, ecfg, pe, kt, df)
+
+
+def _oracle_fitness(env, ecfg, pe, kt, df):
+    perf, _, feas = env_lib.genome_cost(env, ecfg, pe, kt, df)
     return jnp.where(feas, perf, jnp.inf)
 
 
@@ -130,8 +131,6 @@ def make_ga_engine(env: env_lib.EnvArrays, ecfg: env_lib.EnvConfig,
     L = ecfg.levels
     n_df = 3 if ecfg.mix else 1
     genes = 3 if ecfg.mix else 2
-    use_kernel = (cfg.use_kernel if cfg.use_kernel is not None
-                  else jax.default_backend() == "tpu")
 
     def decode(genome):
         pe = env.pe_table[genome[..., 0]]
@@ -142,7 +141,7 @@ def make_ga_engine(env: env_lib.EnvArrays, ecfg: env_lib.EnvConfig,
 
     def fitness(pop):
         pe, kt, df = decode(pop)
-        return _fitness(env, ecfg, pe, kt, df, use_kernel)   # (P,)
+        return _fitness(env, ecfg, pe, kt, df)   # (P,)
 
     def evolve(state: GAState, fit):
         pop, best_val, best_genome, key, gen = state
@@ -397,7 +396,7 @@ def make_local_ga_engine(env: env_lib.EnvArrays, ecfg: env_lib.EnvConfig,
 
     def fitness(pop):
         pe, kt, _ = decode(pop)
-        return _fitness(env, ecfg, pe, kt, df)
+        return _oracle_fitness(env, ecfg, pe, kt, df)
 
     def evolve(state: GAState, fit):
         pop, best_val, best_genome, key, gen = state

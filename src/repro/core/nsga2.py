@@ -50,6 +50,7 @@ import numpy as np
 from repro.core import env as env_lib
 from repro.core import ga as ga_lib
 from repro.costmodel import maestro
+from repro.kernels import ops as kops
 
 _BIG = jnp.float32(1e30)   # finite stand-in for +inf crowding in sort keys
 
@@ -213,9 +214,6 @@ class NSGA2Config:
     crossover_rate: float = 0.5   # per-gene uniform-crossover swap prob
     archive: int = 128            # Pareto-archive capacity (frontier slots)
     seed: int = 0
-    # None = auto: the Pallas batched cost kernel on TPU, the jnp oracle
-    # elsewhere (same policy as GAConfig).
-    use_kernel: Optional[bool] = None
 
 
 class NSGA2State(NamedTuple):
@@ -241,10 +239,11 @@ class NSGA2State(NamedTuple):
     generation: jnp.ndarray     # () int32 generations completed
 
 
-def _multi_costs(env, ecfg, pe, kt, df, use_kernel: bool = False):
+def _multi_costs(env, ecfg, pe, kt, df):
     """(..., N) raw assignment -> (..., 4) aggregated whole-model costs.
 
-    The oracle path evaluates through FLAT per-point rows (layer fields
+    On a TPU a population-sized batch takes the Pallas broadcast kernel.
+    Elsewhere this evaluates through FLAT per-point rows (layer fields
     materialized per point) rather than broadcasting the (N, F) layer table
     against (..., N) assignments: with the broadcast shape XLA hoists
     layer-only subexpressions and reassociates the f32 products, drifting
@@ -253,9 +252,8 @@ def _multi_costs(env, ecfg, pe, kt, df, use_kernel: bool = False):
     nsga2 byte-identical to service-batched nsga2 (asserted by
     benchmarks/bench_frontier.py and tests/test_nsga2.py).
     """
-    if use_kernel and getattr(pe, "ndim", 0) == 2:
-        from repro.kernels import ops
-        lat, en, area, pw = ops.batched_cost(env.layers, pe, kt, df)
+    if kops.on_tpu() and getattr(pe, "ndim", 0) == 2:
+        lat, en, area, pw = kops.batched_cost(env.layers, pe, kt, df)
     else:
         F = env.layers.shape[-1]
         df = jnp.broadcast_to(jnp.asarray(df, jnp.float32), pe.shape)
@@ -281,8 +279,6 @@ def make_nsga2_engine(env: env_lib.EnvArrays, ecfg: env_lib.EnvConfig,
     n_df = 3 if ecfg.mix else 1
     genes = 3 if ecfg.mix else 2
     cons_col = 2 if ecfg.constraint == "area" else 3
-    use_kernel = (cfg.use_kernel if cfg.use_kernel is not None
-                  else jax.default_backend() == "tpu")
 
     def decode(genome):
         pe = env.pe_table[genome[..., 0]]
@@ -293,7 +289,7 @@ def make_nsga2_engine(env: env_lib.EnvArrays, ecfg: env_lib.EnvConfig,
 
     def fitness(pop):
         pe, kt, df = decode(pop)
-        return _multi_costs(env, ecfg, pe, kt, df, use_kernel)   # (P, 4)
+        return _multi_costs(env, ecfg, pe, kt, df)   # (P, 4)
 
     def _update_archive(arch_genomes, arch_costs, pop, fit):
         """Archive ∪ newly evaluated pop -> non-dominated feasible top-A."""

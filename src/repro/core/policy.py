@@ -7,13 +7,13 @@ layers.  An MLP variant exists for the Table IX ablation.
 Heads: one L-way categorical per action (PE level, Buffer level) plus an
 optional 3-way dataflow head for the MIX co-automation agent (SIV-D).
 
-Pure JAX; the LSTM step can route through the fused Pallas kernel
-(kernels/lstm_cell.py) on TPU.
+Pure JAX; on a TPU the LSTM step runs the fused Pallas kernel
+(kernels/lstm_cell.py), elsewhere its jnp oracle (``kernels.ops``).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +30,6 @@ class PolicyConfig:
     levels: int = 12          # L action levels
     mix: bool = False         # add the 3-way dataflow head
     kind: str = "rnn"         # "rnn" (paper) | "mlp" (Table IX ablation)
-    use_kernel: Optional[bool] = None  # None -> pallas kernel iff on TPU
 
     @property
     def n_heads(self) -> int:
@@ -93,10 +92,7 @@ def step(params, cfg: PolicyConfig, obs, state: LSTMState):
         x = obs[None, :] if squeeze else obs
         h = state.h[None, :] if squeeze else state.h
         c = state.c[None, :] if squeeze else state.c
-        use_kernel = (cfg.use_kernel if cfg.use_kernel is not None
-                      else jax.default_backend() == "tpu")
-        h2, c2 = kops.lstm_step(x, h, c, lp["wx"], lp["wh"], lp["b"],
-                                use_kernel=use_kernel)
+        h2, c2 = kops.lstm_step(x, h, c, lp["wx"], lp["wh"], lp["b"])
         if squeeze:
             h2, c2 = h2[0], c2[0]
         feat, new_state = h2, LSTMState(h2, c2)

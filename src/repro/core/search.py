@@ -20,7 +20,7 @@ from repro.core import policy as policy_lib
 from repro.core import reinforce
 from repro.costmodel import dataflows as dfl
 from repro.costmodel import workloads as workloads_lib
-from repro.kernels import ops as kops
+from repro.kernels import ref as kref
 
 
 @dataclasses.dataclass
@@ -93,13 +93,13 @@ def confuciux_search(workload, ecfg: env_lib.EnvConfig,
         wall_seconds=time.time() - t0, epochs=rcfg.epochs)
 
 
-def per_layer_optima(workload, ecfg: env_lib.EnvConfig,
-                     use_kernel: bool = False):
+def per_layer_optima(workload, ecfg: env_lib.EnvConfig):
     """SIV-B LS study: the full (L x L) action-pair sweep for every layer.
 
     Returns dict with the (N, L, L) latency/energy grids and per-layer argmin
-    pairs -- the data behind Fig. 5's heatmaps.  One batched cost-model call
-    evaluates all N * L * L cells.
+    pairs -- the data behind Fig. 5's heatmaps.  One batched call of the jnp
+    cost model (``kernels/ref.py``, on every platform) evaluates all
+    N * L * L cells.
     """
     if isinstance(workload, str):
         workload = workloads_lib.get_workload(workload)
@@ -110,10 +110,8 @@ def per_layer_optima(workload, ecfg: env_lib.EnvConfig,
     # (L*L, N) design batch: same pair applied to each layer independently.
     pe = jnp.tile(pe_g.reshape(-1, 1), (1, N))
     kt = jnp.tile(kt_g.reshape(-1, 1), (1, N))
-    layers = env.layers
-    lat, en, area, power = kops.batched_cost(layers, pe, kt,
-                                             float(ecfg.dataflow),
-                                             use_kernel=use_kernel)
+    df = jnp.full(pe.shape, ecfg.dataflow, jnp.float32)
+    lat, en, area, power = kref.cost_eval_ref(env.layers.T, pe, kt, df)
     lat = np.asarray(lat).reshape(L, L, N).transpose(2, 0, 1)
     en = np.asarray(en).reshape(L, L, N).transpose(2, 0, 1)
     area = np.asarray(area).reshape(L, L, N).transpose(2, 0, 1)

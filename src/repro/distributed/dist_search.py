@@ -384,7 +384,7 @@ def _fanout_ga_device(subs) -> list:
     Per-shard carries differ only in their seed; the generation step is
     shared, so one compile drives every island.  The fitness hot-spot goes
     through :func:`repro.core.ga._fitness`, which dispatches the Pallas
-    batched cost kernel on TPU (``GAConfig.use_kernel``).
+    batched cost kernel on TPU (``kernels.ops.on_tpu``).
     """
     from repro.api import optimizers as api_optimizers
 

@@ -6,8 +6,8 @@
   flash_decode   -- online-softmax single-token GQA attention for long-KV
                     serving shapes
 
-``ops`` exposes shape-flexible wrappers; ``ref`` holds the pure-jnp oracles.
-Off-TPU everything runs through ``interpret=True``.
+``ops`` exposes shape-flexible wrappers that run the kernels on a TPU and
+the pure-jnp oracles of ``ref`` elsewhere (``ops.on_tpu``).
 """
 from repro.kernels.ops import batched_cost, decode_attention, lstm_step
 

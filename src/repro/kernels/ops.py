@@ -1,9 +1,10 @@
 """Public, shape-flexible entry points for the Pallas kernels.
 
-Each op pads its inputs to the kernel's tile multiples, dispatches to the
-``pl.pallas_call`` implementation (interpret mode off-TPU), and slices the
-result back.  ``use_kernel=False`` routes to the pure-jnp oracle in ref.py --
-the ops are drop-in interchangeable, which is how the tests validate them.
+The one place that picks the implementation from the platform: on a TPU
+(:func:`on_tpu`) each op pads its inputs to the kernel's tile multiples,
+runs the ``pl.pallas_call`` and slices the result back; elsewhere it runs
+the pure-jnp oracle in ref.py.  The tests call the private ``_*_pallas``
+halves (interpret mode off-TPU) against the oracles.
 """
 from __future__ import annotations
 
@@ -11,11 +12,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.costmodel.layers import NUM_FIELDS
 from repro.kernels import costmodel_eval, flash_decode, lstm_cell, ref
 
 
+def on_tpu() -> bool:
+    """Run the Pallas kernels (and shape batches for them), not the oracles."""
+    return jax.default_backend() == "tpu"
+
+
 def _interpret() -> bool:
+    # Apart from on_tpu(): tests route to a kernel on the CPU, in interpret
+    # mode or compiled for a described TPU.
     return jax.default_backend() != "tpu"
 
 
@@ -29,37 +36,41 @@ def _pad_to(x, axis: int, mult: int, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-def batched_cost(layers, pe, kt, df, *, use_kernel: bool = True):
+def _cost_operands(layers, pe, kt, df):
+    """f32 layers, and f32 pe/kt/df broadcast to the (B, N) batch."""
+    layers = jnp.asarray(layers, jnp.float32)
+    shape = jnp.broadcast_shapes(jnp.shape(pe), layers.shape[:-1])
+    return (layers, *(jnp.broadcast_to(jnp.asarray(a, jnp.float32), shape)
+                      for a in (pe, kt, df)))
+
+
+def _pad_points(pe, kt, df):   # benign all-ones dummies, sliced off after
+    return tuple(_pad_to(_pad_to(a, 0, costmodel_eval.TB, 1.0), 1,
+                         costmodel_eval.TN, 1.0) for a in (pe, kt, df))
+
+
+def batched_cost(layers, pe, kt, df):
     """Evaluate a (B, N) batch of per-layer assignments.
 
     layers: (N, NUM_FIELDS); pe/kt/df: (B, N) (df may be scalar).
     Returns (latency, energy, area, power), each (B, N) f32.
     """
-    layers = jnp.asarray(layers, jnp.float32)
-    N = layers.shape[0]
-    pe = jnp.asarray(pe, jnp.float32)
-    B = pe.shape[0]
-    kt = jnp.broadcast_to(jnp.asarray(kt, jnp.float32), (B, N))
-    df = jnp.broadcast_to(jnp.asarray(df, jnp.float32), (B, N))
+    if on_tpu():
+        return _batched_cost_pallas(layers, pe, kt, df)
+    layers, pe, kt, df = _cost_operands(layers, pe, kt, df)
+    return ref.cost_eval_ref(layers.T, pe, kt, df)
 
-    layers_t = layers.T  # (NUM_FIELDS, N)
-    if not use_kernel:
-        return ref.cost_eval_ref(layers_t, pe, kt, df)
 
-    # Pad layers with benign dummies (all-ones layer) and slice out after.
-    layers_p = _pad_to(layers_t, 1, costmodel_eval.TN, value=1.0)
-    pe_p = _pad_to(_pad_to(pe, 0, costmodel_eval.TB, 1.0), 1,
-                   costmodel_eval.TN, 1.0)
-    kt_p = _pad_to(_pad_to(kt, 0, costmodel_eval.TB, 1.0), 1,
-                   costmodel_eval.TN, 1.0)
-    df_p = _pad_to(_pad_to(df, 0, costmodel_eval.TB, 1.0), 1,
-                   costmodel_eval.TN, 1.0)
-    outs = costmodel_eval.cost_eval_padded(layers_p, pe_p, kt_p, df_p,
+def _batched_cost_pallas(layers, pe, kt, df):
+    layers, pe, kt, df = _cost_operands(layers, pe, kt, df)
+    B, N = pe.shape
+    layers_p = _pad_to(layers.T, 1, costmodel_eval.TN, value=1.0)
+    outs = costmodel_eval.cost_eval_padded(layers_p, *_pad_points(pe, kt, df),
                                            interpret=_interpret())
     return tuple(o[:B, :N] for o in outs)
 
 
-def batched_cost_multi(layers, pe, kt, df, *, use_kernel: bool = True):
+def batched_cost_multi(layers, pe, kt, df):
     """Evaluate a (B, N) batch where EVERY ROW has its own layer descriptors.
 
     layers: (B, N, NUM_FIELDS); pe/kt/df: (B, N) (kt/df may broadcast).
@@ -72,39 +83,33 @@ def batched_cost_multi(layers, pe, kt, df, *, use_kernel: bool = True):
     must mask their OWN padding (the batcher pads its rows with
     ``repeat=0`` layers, which zero all four outputs).
     """
-    layers = jnp.asarray(layers, jnp.float32)
-    B, N = layers.shape[0], layers.shape[1]
-    pe = jnp.broadcast_to(jnp.asarray(pe, jnp.float32), (B, N))
-    kt = jnp.broadcast_to(jnp.asarray(kt, jnp.float32), (B, N))
-    df = jnp.broadcast_to(jnp.asarray(df, jnp.float32), (B, N))
+    if on_tpu():
+        return _batched_cost_multi_pallas(layers, pe, kt, df)
+    layers, pe, kt, df = _cost_operands(layers, pe, kt, df)
+    return ref.cost_eval_multi_ref(layers.transpose(0, 2, 1), pe, kt, df)
 
-    layers_bt = layers.transpose(0, 2, 1)  # (B, NUM_FIELDS, N)
-    if not use_kernel:
-        return ref.cost_eval_multi_ref(layers_bt, pe, kt, df)
 
-    layers_p = _pad_to(_pad_to(layers_bt, 0, costmodel_eval.TB, 1.0), 2,
+def _batched_cost_multi_pallas(layers, pe, kt, df):
+    layers, pe, kt, df = _cost_operands(layers, pe, kt, df)
+    B, N = pe.shape
+    layers_p = _pad_to(_pad_to(layers.transpose(0, 2, 1), 0,
+                               costmodel_eval.TB, 1.0), 2,
                        costmodel_eval.TN, 1.0)
-    pe_p = _pad_to(_pad_to(pe, 0, costmodel_eval.TB, 1.0), 1,
-                   costmodel_eval.TN, 1.0)
-    kt_p = _pad_to(_pad_to(kt, 0, costmodel_eval.TB, 1.0), 1,
-                   costmodel_eval.TN, 1.0)
-    df_p = _pad_to(_pad_to(df, 0, costmodel_eval.TB, 1.0), 1,
-                   costmodel_eval.TN, 1.0)
-    outs = costmodel_eval.cost_eval_multi_padded(layers_p, pe_p, kt_p, df_p,
-                                                 interpret=_interpret())
+    outs = costmodel_eval.cost_eval_multi_padded(
+        layers_p, *_pad_points(pe, kt, df), interpret=_interpret())
     return tuple(o[:B, :N] for o in outs)
 
 
-def lstm_step(x, h, c, wx, wh, b, *, use_kernel: bool = True):
+def lstm_step(x, h, c, wx, wh, b):
     """One LSTM cell step.  x: (B, I); h/c: (B, H); returns (h', c').
 
     The kernel path is differentiable: its backward pass is the oracle's
     (a Mosaic kernel has no autodiff rule, and REINFORCE differentiates
     the policy step).
     """
-    if not use_kernel:
-        return _lstm_ref(x, h, c, wx, wh, b)
-    return _lstm_kernel(x, h, c, wx, wh, b)
+    if on_tpu():
+        return _lstm_step_pallas(x, h, c, wx, wh, b)
+    return _lstm_ref(x, h, c, wx, wh, b)
 
 
 def _lstm_ref(x, h, c, wx, wh, b):
@@ -112,7 +117,7 @@ def _lstm_ref(x, h, c, wx, wh, b):
 
 
 @jax.custom_vjp
-def _lstm_kernel(x, h, c, wx, wh, b):
+def _lstm_step_pallas(x, h, c, wx, wh, b):
     B, I = x.shape
     H = h.shape[-1]
     # Pad the observation dim to the lane width and B to the batch tile.
@@ -128,30 +133,29 @@ def _lstm_kernel(x, h, c, wx, wh, b):
     return h_new[:B], c_new[:B]
 
 
-def _lstm_kernel_fwd(*args):
-    return _lstm_kernel(*args), args
+def _lstm_step_pallas_fwd(*args):
+    return _lstm_step_pallas(*args), args
 
 
-def _lstm_kernel_bwd(args, g):
+def _lstm_step_pallas_bwd(args, g):
     return jax.vjp(_lstm_ref, *args)[1](g)
 
 
-_lstm_kernel.defvjp(_lstm_kernel_fwd, _lstm_kernel_bwd)
+_lstm_step_pallas.defvjp(_lstm_step_pallas_fwd, _lstm_step_pallas_bwd)
 
 
-def decode_attention(q, k, v, *, use_kernel: bool = True):
+def decode_attention(q, k, v):
     """Single-token GQA attention over a KV cache.
 
     q: (B, Hq, D); k/v: (B, T, Hkv, D).  Returns (B, Hq, D).
     """
-    if not use_kernel:
-        return ref.flash_decode_ref(q, k, v)
-    T = k.shape[1]
-    # Pad the cache length with -inf-masked dummy keys: we pad K with a huge
-    # negative value in the first lane?  Simpler and exact: pad with zeros
-    # and mask by appending matching zero-value V and correcting the softmax
-    # -- instead we require T % TT == 0 here and fall back otherwise.
-    if T % flash_decode.TT != 0:
+    if on_tpu():
+        return _decode_attention_pallas(q, k, v)
+    return ref.flash_decode_ref(q, k, v)
+
+
+def _decode_attention_pallas(q, k, v):
+    if k.shape[1] % flash_decode.TT != 0:
         return ref.flash_decode_ref(q, k, v)
     return flash_decode.flash_decode_padded(
         jnp.asarray(q, jnp.float32), jnp.asarray(k, jnp.float32),

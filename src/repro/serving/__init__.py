@@ -14,6 +14,7 @@ from repro.serving.http_service import (  # noqa: F401
 )
 from repro.serving.search_service import (  # noqa: F401
     BATCHED_METHODS,
+    COSTS_BATCHED_METHODS,
     RAW_BATCHED_METHODS,
     SearchCancelled,
     SearchService,

@@ -12,8 +12,8 @@ funneled through one dispatcher thread that:
      (:func:`dedup_point_rows`), the memo cache's own identity;
   3. consults the :class:`~repro.serving.cost_cache.CostMemoCache` and
      evaluates only the genuinely new points in ONE fused call -- the Pallas
-     per-row-layers kernel (``ops.batched_cost_multi``) on TPU, the jitted
-     jnp oracle elsewhere;
+     per-row-layers kernel (``ops.batched_cost_multi``) when
+     ``kernels.ops.on_tpu()``, the jitted jnp oracle elsewhere;
   4. re-assembles each request's per-layer value tensor and aggregates it
      with the exact jnp reductions of :func:`repro.core.env.genome_cost`.
 
@@ -40,6 +40,8 @@ import numpy as np
 from repro.core import env as env_lib
 from repro.costmodel import maestro
 from repro.costmodel.layers import F_TYPE, NUM_FIELDS, TYPE_NAMES
+from repro.kernels import ops
+from repro.kernels.costmodel_eval import TN
 from repro.obs import instrument as obs_instrument
 from repro.obs import recorder as obs_recorder
 from repro.obs import state as obs_state
@@ -137,9 +139,7 @@ class CostEvalBatcher:
     ``window_ms`` is the accumulation window after the first pending item;
     while a dispatch executes, new arrivals queue up naturally, so steady-
     state fusion widths track the number of concurrently evaluating
-    searches.  ``use_kernel=None`` auto-selects the Pallas per-row-layers
-    kernel on TPU and the jitted jnp oracle elsewhere (interpret-mode Pallas
-    would dominate CPU runs).
+    searches.  Fresh points go to :func:`eval_point_rows`.
 
     ``dispatch_workers`` sizes the dispatch pool: with N > 1, up to N fused
     dispatches execute concurrently (XLA releases the GIL during execution,
@@ -152,14 +152,11 @@ class CostEvalBatcher:
 
     def __init__(self, cache: Optional[CostMemoCache] = None,
                  window_ms: float = 2.0,
-                 use_kernel: Optional[bool] = None,
                  dispatch_workers: int = 1,
                  join_timeout_s: float = 5.0):
         self.cache = cache if cache is not None else CostMemoCache()
         self._window_s = max(window_ms, 0.0) / 1e3
         self._join_timeout_s = float(join_timeout_s)
-        self._use_kernel = (use_kernel if use_kernel is not None
-                            else jax.default_backend() == "tpu")
         self._pending: List[_Item] = []
         self._cv = threading.Condition()
         self._closed = False
@@ -404,11 +401,17 @@ class CostEvalBatcher:
             off += n
 
     def _eval_points(self, rows: np.ndarray) -> np.ndarray:
-        return eval_point_rows(rows, self._use_kernel)
+        return eval_point_rows(rows)
 
 
-def eval_point_rows(rows: np.ndarray, use_kernel: bool) -> np.ndarray:
+def eval_point_rows(rows: np.ndarray,
+                    use_kernel: Optional[bool] = None) -> np.ndarray:
     """Evaluate (M, ROW_WIDTH) fresh points -> (M, 4) f32 costs.
+
+    ``use_kernel`` picks the shape: True tiles the rows to multiples of TN
+    for ``ops.batched_cost_multi`` (the per-row kernel on a TPU), False pads
+    them to a power of two for the jitted oracle, which bounds recompiles.
+    None, the default, is ``ops.on_tpu()``.
 
     Per-row results are bit-stable across batch size and padding (the
     computation is elementwise per row), so any caller packing the same row
@@ -416,11 +419,10 @@ def eval_point_rows(rows: np.ndarray, use_kernel: bool) -> np.ndarray:
     service-batched byte-identity rest on.
     """
     M = rows.shape[0]
+    if use_kernel is None:
+        use_kernel = ops.on_tpu()
     if use_kernel:
-        from repro.kernels import ops
-
         # Tile the flat point list into the kernel's (B', TN) lanes.
-        from repro.kernels.costmodel_eval import TN
         Mp = -(-M // TN) * TN
         pad = np.ones((Mp - M, ROW_WIDTH), np.float32)
         pad[:, NUM_FIELDS - 1] = 0.0            # repeat=0: benign rows
@@ -435,7 +437,6 @@ def eval_point_rows(rows: np.ndarray, use_kernel: bool) -> np.ndarray:
                         np.asarray(area), np.asarray(pw)],
                        axis=-1).reshape(Mp, 4)
         return out[:M]
-    # jnp-oracle path: pad to pow2 buckets to bound recompiles.
     Mp = _next_pow2(M)
     rp = np.ones((Mp, ROW_WIDTH), np.float32)
     rp[:M] = rows
@@ -484,7 +485,7 @@ def pack_point_rows(layers: np.ndarray, pe, kt, df) -> np.ndarray:
     return points
 
 
-def make_local_costs_eval(env, ecfg, use_kernel: Optional[bool] = None):
+def make_local_costs_eval(env, ecfg):
     """Serial nsga2's default fitness hook: ``eval_fn(pe, kt, df) -> (b, 4)``
     running the EXACT per-point and aggregation programs a
     :class:`CostEvalBatcher` dispatches -- minus the queue, fusion window
@@ -496,15 +497,13 @@ def make_local_costs_eval(env, ecfg, use_kernel: Optional[bool] = None):
     """
     layers = np.asarray(env.layers, np.float32)
     budget = np.float32(env.budget)
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
     agg = _agg_multi_fn(ecfg)
 
     def eval_fn(pe, kt, df):
         pe = np.asarray(pe, np.float32)
         b, N = pe.shape
         rows = pack_point_rows(layers, pe, kt, df)
-        vals = eval_point_rows(rows, use_kernel).reshape(b, N, 4)
+        vals = eval_point_rows(rows).reshape(b, N, 4)
         return np.asarray(agg(jnp.asarray(vals), budget))
 
     return eval_fn

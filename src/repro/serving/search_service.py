@@ -50,7 +50,7 @@ import itertools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -89,6 +89,9 @@ def _clone_exception(err: BaseException) -> BaseException:
     return clone
 
 
+# ``SearchService._instrument`` routes a request by which of the three
+# tuples below holds its method; a method in none evaluates on its own.
+#
 # Methods whose host-side eval loop accepts an injected genome-level
 # ``eval_fn`` and can therefore be fused by the cross-request batcher.
 BATCHED_METHODS = ("random", "grid", "bo")
@@ -116,10 +119,6 @@ class ServiceConfig:
     max_workers: int = 8          # concurrent searches in flight
     cache_entries: int = 2 ** 20  # per-point memo capacity
     window_ms: float = 2.0        # batcher accumulation window
-    use_kernel: Optional[bool] = None   # None: Pallas kernel on TPU only
-    batched_methods: Tuple[str, ...] = BATCHED_METHODS
-    raw_batched_methods: Tuple[str, ...] = RAW_BATCHED_METHODS
-    costs_batched_methods: Tuple[str, ...] = COSTS_BATCHED_METHODS
     dispatch_workers: int = 1     # fused-dispatch pool size (batcher threads)
     default_progress_every: int = 200   # service-side chunking when the
     #                                     request carries no callback
@@ -240,7 +239,6 @@ class SearchService:
         else:
             self.cache = CostMemoCache(cfg.cache_entries)
         self.batcher = CostEvalBatcher(self.cache, window_ms=cfg.window_ms,
-                                       use_kernel=cfg.use_kernel,
                                        dispatch_workers=cfg.dispatch_workers)
         self._pool = ThreadPoolExecutor(
             max_workers=cfg.max_workers, thread_name_prefix="search-worker")
@@ -364,11 +362,11 @@ class SearchService:
                           else self.cfg.default_progress_every)
         options = dict(request.options)
         method = api_registry.get_optimizer(request.method).name
-        if method in self.cfg.batched_methods:
+        if method in BATCHED_METHODS:
             options["eval_fn"] = self._make_eval_fn(ticket)
-        elif method in self.cfg.raw_batched_methods:
+        elif method in RAW_BATCHED_METHODS:
             options["eval_fn"] = self._make_raw_eval_fn(ticket)
-        elif method in self.cfg.costs_batched_methods:
+        elif method in COSTS_BATCHED_METHODS:
             options["eval_fn"] = self._make_costs_eval_fn(ticket)
         return dataclasses.replace(
             request, options=options, on_progress=on_progress,

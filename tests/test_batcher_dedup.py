@@ -98,14 +98,14 @@ def test_persistent_shard_with_float_unique_keys_still_hits(tmp_path):
     old_uniq = np.unique(rows, axis=0)
     d = str(tmp_path / "cache")
     c = PersistentCostCache(d, flush_every=10 ** 6)
-    vals = eval_point_rows(old_uniq, use_kernel=False)
+    vals = eval_point_rows(old_uniq)
     c.put_many([u.tobytes() for u in old_uniq], [v.copy() for v in vals])
     c.close()
 
     c2 = PersistentCostCache(d)
     assert len(c2) == len(old_uniq)
     N = layers.shape[0]
-    bat = CostEvalBatcher(cache=c2, window_ms=0.0, use_kernel=False)
+    bat = CostEvalBatcher(cache=c2, window_ms=0.0)
     try:
         got = bat.evaluate_costs(
             layers, rows[:, ROW_WIDTH - 3].reshape(-1, N),

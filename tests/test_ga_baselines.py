@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.core import baselines, env as env_lib, ga as ga_lib
 from repro.costmodel import dataflows as dfl
 from repro.costmodel.layers import LayerSpec
+from repro.kernels import ops
 
 
 def _wl():
@@ -33,17 +34,19 @@ def test_baseline_ga_improves():
     assert len(finite) and finite[-1] <= finite[0]
 
 
-def test_ga_fitness_kernel_path_matches_oracle():
-    """GAConfig.use_kernel routes fitness through the Pallas batched cost
-    kernel (interpret mode off-TPU) with the same feasibility/objective."""
+def test_ga_fitness_kernel_path_matches_oracle(monkeypatch):
+    """On a TPU (``ops.on_tpu``, forced here) fitness routes through the
+    Pallas batched cost kernel (interpret mode off-TPU) with the same
+    feasibility/objective as the oracle."""
     env = env_lib.make_env(_wl(), ECFG)
     key = jax.random.PRNGKey(0)
     pe = jax.random.choice(key, env.pe_table, (8, env.num_layers))
     kt = jax.random.choice(jax.random.fold_in(key, 1), env.kt_table,
                            (8, env.num_layers))
     df = jnp.asarray(ECFG.dataflow, jnp.int32)
-    oracle = ga_lib._fitness(env, ECFG, pe, kt, df, use_kernel=False)
-    kernel = ga_lib._fitness(env, ECFG, pe, kt, df, use_kernel=True)
+    oracle = ga_lib._fitness(env, ECFG, pe, kt, df)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    kernel = ga_lib._fitness(env, ECFG, pe, kt, df)
     np.testing.assert_array_equal(np.isfinite(oracle), np.isfinite(kernel))
     finite = np.isfinite(np.asarray(oracle))
     np.testing.assert_allclose(np.asarray(kernel)[finite],

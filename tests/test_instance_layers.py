@@ -102,12 +102,12 @@ def test_kernels_match_oracle_on_instance_rows(kernel):
     B, N = 9, layers.shape[0]
     pe, kt, df = (x.reshape(B, N) for x in _points(rng, B * N))
     if kernel == "broadcast":
-        got = ops.batched_cost(layers, pe, kt, df, use_kernel=True)
-        want = ops.batched_cost(layers, pe, kt, df, use_kernel=False)
+        got = ops._batched_cost_pallas(layers, pe, kt, df)
+        want = ops.batched_cost(layers, pe, kt, df)
     else:
         per_row = np.stack([layers[rng.permutation(N)] for _ in range(B)])
-        got = ops.batched_cost_multi(per_row, pe, kt, df, use_kernel=True)
-        want = ops.batched_cost_multi(per_row, pe, kt, df, use_kernel=False)
+        got = ops._batched_cost_multi_pallas(per_row, pe, kt, df)
+        want = ops.batched_cost_multi(per_row, pe, kt, df)
     for gv, wv in zip(got, want):
         np.testing.assert_allclose(np.asarray(gv), np.asarray(wv),
                                    rtol=1e-6, atol=0)

@@ -45,8 +45,8 @@ def test_costmodel_kernel_shapes(B, N):
                             17).astype(jnp.float32)
     df = jax.random.randint(jax.random.fold_in(key, 2), (B, N), 0,
                             3).astype(jnp.float32)
-    got = ops.batched_cost(layers, pe, kt, df, use_kernel=True)
-    want = ops.batched_cost(layers, pe, kt, df, use_kernel=False)
+    got = ops._batched_cost_pallas(layers, pe, kt, df)
+    want = ops.batched_cost(layers, pe, kt, df)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-5, atol=1e-2)
@@ -63,8 +63,8 @@ def test_costmodel_multi_kernel_shapes(B, N):
                             17).astype(jnp.float32)
     df = jax.random.randint(jax.random.fold_in(key, 2), (B, N), 0,
                             3).astype(jnp.float32)
-    got = ops.batched_cost_multi(layers, pe, kt, df, use_kernel=True)
-    want = ops.batched_cost_multi(layers, pe, kt, df, use_kernel=False)
+    got = ops._batched_cost_multi_pallas(layers, pe, kt, df)
+    want = ops.batched_cost_multi(layers, pe, kt, df)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-5, atol=1e-2)
@@ -79,8 +79,8 @@ def test_costmodel_multi_kernel_matches_broadcast_kernel():
     kt = rng.integers(1, 17, size=(B, 9)).astype(np.float32)
     df = rng.integers(0, 3, size=(B, 9)).astype(np.float32)
     multi = ops.batched_cost_multi(np.broadcast_to(layers, (B,) + layers.shape),
-                                   pe, kt, df, use_kernel=False)
-    broad = ops.batched_cost(layers, pe, kt, df, use_kernel=False)
+                                   pe, kt, df)
+    broad = ops.batched_cost(layers, pe, kt, df)
     for m, b in zip(multi, broad):
         np.testing.assert_array_equal(np.asarray(m), np.asarray(b))
 
@@ -94,8 +94,8 @@ def test_costmodel_kernel_property(seed, B, N):
     pe = rng.integers(1, 161, (B, N)).astype(np.float32)
     kt = rng.integers(1, 17, (B, N)).astype(np.float32)
     df = rng.integers(0, 3, (B, N)).astype(np.float32)
-    got = ops.batched_cost(layers, pe, kt, df, use_kernel=True)
-    want = ops.batched_cost(layers, pe, kt, df, use_kernel=False)
+    got = ops._batched_cost_pallas(layers, pe, kt, df)
+    want = ops.batched_cost(layers, pe, kt, df)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-5, atol=1e-2)
@@ -111,8 +111,8 @@ def test_lstm_kernel_shapes(B, I, H):
     wx = jax.random.normal(jax.random.fold_in(key, 3), (I, 4 * H)) * 0.1
     wh = jax.random.normal(jax.random.fold_in(key, 4), (H, 4 * H)) * 0.1
     b = jax.random.normal(jax.random.fold_in(key, 5), (4 * H,)) * 0.1
-    h1, c1 = ops.lstm_step(x, h, c, wx, wh, b, use_kernel=True)
-    h2, c2 = ops.lstm_step(x, h, c, wx, wh, b, use_kernel=False)
+    h1, c1 = ops._lstm_step_pallas(x, h, c, wx, wh, b)
+    h2, c2 = ops.lstm_step(x, h, c, wx, wh, b)
     np.testing.assert_allclose(h1, h2, atol=1e-5)
     np.testing.assert_allclose(c1, c2, atol=1e-5)
 
@@ -126,8 +126,8 @@ def test_flash_decode_kernel(B, Hq, Hkv, D, T):
     q = jax.random.normal(key, (B, Hq, D))
     k = jax.random.normal(jax.random.fold_in(key, 1), (B, T, Hkv, D))
     v = jax.random.normal(jax.random.fold_in(key, 2), (B, T, Hkv, D))
-    o1 = ops.decode_attention(q, k, v, use_kernel=True)
-    o2 = ops.decode_attention(q, k, v, use_kernel=False)
+    o1 = ops._decode_attention_pallas(q, k, v)
+    o2 = ops.decode_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-4)
 
 
@@ -137,7 +137,7 @@ def test_flash_decode_fallback_unaligned():
     q = jax.random.normal(key, (1, 4, 128))
     k = jax.random.normal(key, (1, 700, 2, 128))
     v = jax.random.normal(key, (1, 700, 2, 128))
-    o = ops.decode_attention(q, k, v, use_kernel=True)
+    o = ops._decode_attention_pallas(q, k, v)
     assert o.shape == (1, 4, 128) and bool(jnp.isfinite(o).all())
 
 
@@ -163,7 +163,7 @@ MIX_NAMES = ["qwen1p5_0p5b", "whisper_small", "mamba2_130m"]
 
 
 def test_mix_rows_oracle_wrapper_is_exact():
-    """batched_cost_multi(use_kernel=False) == cost_eval_multi_ref verbatim:
+    """batched_cost_multi off a TPU == cost_eval_multi_ref verbatim:
     the wrapper's transpose/broadcast plumbing is lossless on ragged mix
     rows from three different model configs."""
     from repro.kernels import ref
@@ -175,7 +175,7 @@ def test_mix_rows_oracle_wrapper_is_exact():
     pe = rng.integers(1, 161, (B, N)).astype(np.float32)
     kt = rng.integers(1, 17, (B, N)).astype(np.float32)
     df = rng.integers(0, 3, (B, N)).astype(np.float32)
-    got = ops.batched_cost_multi(rows, pe, kt, df, use_kernel=False)
+    got = ops.batched_cost_multi(rows, pe, kt, df)
     want = ref.cost_eval_multi_ref(rows.transpose(0, 2, 1), pe, kt, df)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
@@ -192,8 +192,8 @@ def test_mix_rows_kernel_matches_oracle(seed):
     pe = rng.integers(1, 161, (B, N)).astype(np.float32)
     kt = rng.integers(1, 17, (B, N)).astype(np.float32)
     df = rng.integers(0, 3, (B, N)).astype(np.float32)
-    got = ops.batched_cost_multi(rows, pe, kt, df, use_kernel=True)
-    want = ops.batched_cost_multi(rows, pe, kt, df, use_kernel=False)
+    got = ops._batched_cost_multi_pallas(rows, pe, kt, df)
+    want = ops.batched_cost_multi(rows, pe, kt, df)
     for g, w in zip(got, want):
         g, w = np.asarray(g), np.asarray(w)
         np.testing.assert_allclose(g, w, rtol=5e-7)

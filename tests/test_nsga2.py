@@ -54,7 +54,7 @@ def test_injected_eval_fn_is_deterministic_and_matches_adapter():
     from repro.serving import batcher as batcher_lib
 
     env = env_lib.make_env(workloads.get_workload("ncf"), ECFG)
-    eval_fn = batcher_lib.make_local_costs_eval(env, ECFG, use_kernel=False)
+    eval_fn = batcher_lib.make_local_costs_eval(env, ECFG)
     s1, h1 = nsga2.run_nsga2_search(NCF, ECFG, CFG, eval_fn=eval_fn,
                                     env=env)
     s2, h2 = nsga2.run_nsga2_search(NCF, ECFG, CFG, chunk=3,

@@ -350,8 +350,7 @@ def test_batcher_hammer_exact_counters_and_attribution():
     N = layers.shape[0]
     T, K, B = 4, 3, 8            # threads x submits x genomes-per-submit
     workers = 2
-    b = CostEvalBatcher(window_ms=1.0, use_kernel=False,
-                        dispatch_workers=workers)
+    b = CostEvalBatcher(window_ms=1.0, dispatch_workers=workers)
     recs = [recorder.FlightRecorder(engine=f"t{i}") for i in range(T)]
     fits = [None] * T
     errors = []
@@ -422,7 +421,7 @@ def test_batcher_phases_sum_to_dispatch_seconds():
     layers = np.asarray(env.layers, np.float32)
     N = layers.shape[0]
     rng = np.random.default_rng(5)
-    b = CostEvalBatcher(window_ms=0.0, use_kernel=False)
+    b = CostEvalBatcher(window_ms=0.0)
     try:
         for _ in range(6):
             pe = rng.integers(1, 64, (64, N)).astype(np.float32)

@@ -15,9 +15,11 @@ import pytest
 
 from repro import api
 from repro.core import env as env_lib
-from repro.serving import (CostEvalBatcher, CostMemoCache,
-                           PersistentCostCache, SearchCancelled,
-                           SearchService, ServiceConfig)
+from repro.serving import (BATCHED_METHODS, COSTS_BATCHED_METHODS,
+                           RAW_BATCHED_METHODS, CostEvalBatcher,
+                           CostMemoCache, PersistentCostCache,
+                           SearchCancelled, SearchService, SearchTicket,
+                           ServiceConfig)
 from repro.serving.batcher import ROW_WIDTH
 
 ECFG = env_lib.EnvConfig(platform="cloud")
@@ -117,6 +119,27 @@ def test_run_all_preserves_request_order(svc):
     outs = svc.run_all([_req("random", eps=150, seed=s) for s in range(3)])
     assert [o.seed for o in outs] == [0, 1, 2]
     assert all(o.method == "random" for o in outs)
+
+
+# Which eval_fn the service injects into each registered method.
+ROUTES = {"random": "genome", "grid": "genome", "bo": "genome",
+          "ga": "raw", "sa": "raw", "relaxed": "raw", "nsga2": "costs",
+          "reinforce": None, "two_stage": None, "a2c": None, "ppo2": None,
+          "fanout": None, "dist_reinforce": None}
+
+
+@pytest.mark.parametrize("method", api.registry.list_optimizers())
+def test_instrument_routes_each_method(svc, method, monkeypatch):
+    """``_instrument`` injects the genome-level, raw or costs ``eval_fn``
+    by the module constants, and none where a method is in none of them."""
+    for maker, kind in (("_make_eval_fn", "genome"),
+                        ("_make_raw_eval_fn", "raw"),
+                        ("_make_costs_eval_fn", "costs")):
+        monkeypatch.setattr(svc, maker, lambda ticket, kind=kind: kind)
+    req = svc._instrument(SearchTicket(0, _req(method)))
+    assert req.options.get("eval_fn") == ROUTES[method]
+    assert sum(method in t for t in (BATCHED_METHODS, RAW_BATCHED_METHODS,
+                                     COSTS_BATCHED_METHODS)) <= 1
 
 
 # ---------------------------------------------------------------------------
@@ -634,7 +657,7 @@ def test_evaluate_costs_matches_local_eval_and_shares_cache():
         costs = bat.evaluate_costs(layers, pe, kt, df, ECFG,
                                    float(env.budget))
         assert costs.shape == (b, 4)
-        local = make_local_costs_eval(env, ECFG, use_kernel=False)
+        local = make_local_costs_eval(env, ECFG)
         np.testing.assert_array_equal(costs,
                                       np.asarray(local(pe, kt, df)))
         # Scalar fitness over the same points: all cache hits, zero fresh.

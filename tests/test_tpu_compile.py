@@ -80,8 +80,10 @@ def one_chip():
 @pytest.mark.parametrize("kernel", sorted(CASES))
 def test_kernel_compiles_for_v5e(kernel, one_chip, monkeypatch):
     wrapper, shapes = CASES[kernel]
-    # ops picks interpret mode from the default backend, which is the CPU
-    # here; the target of this compile is the described TPU.
+    # ops picks the kernel and interpret mode from the default backend,
+    # which is the CPU here; the target of this compile is the described
+    # TPU.
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes]
