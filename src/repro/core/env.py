@@ -140,8 +140,15 @@ def select_objective(total_lat, total_en, cfg: EnvConfig):
         return total_lat
     if cfg.objective == "energy":
         return total_en
-    w = jnp.float32(cfg.blend_weight)
-    return total_lat ** w * total_en ** (jnp.float32(1.0) - w)
+    xp = _xp(total_lat)
+    w = xp.float32(cfg.blend_weight)
+    return total_lat ** w * total_en ** (xp.float32(1.0) - w)
+
+
+def _xp(x):
+    """The array module that reduces ``x``: NumPy for a NumPy array, ``jnp``
+    for a jax array or tracer."""
+    return np if isinstance(x, np.ndarray) else jnp
 
 
 def sum_layers(x):
@@ -152,13 +159,15 @@ def sum_layers(x):
     aggregation program and a reduction fused after a kernel can then
     differ by an ulp for the same per-layer values.  Explicit adds of
     contiguous halves are never reassociated, so every program that
-    aggregates the same values gets the same bits.
+    aggregates the same values gets the same bits -- and NumPy, given a
+    NumPy array, makes the same adds in the same order.
     """
+    xp = _xp(x)
     while x.shape[-1] > 1:
         h = x.shape[-1] // 2
         head = x[..., :h] + x[..., h:2 * h]
         x = (head if x.shape[-1] % 2 == 0
-             else jnp.concatenate([head, x[..., 2 * h:]], axis=-1))
+             else xp.concatenate([head, x[..., 2 * h:]], axis=-1))
     return x[..., 0]
 
 
@@ -173,15 +182,21 @@ def aggregate_costs_multi(lat, en, area, pw, cfg: EnvConfig, budget):
     Pallas-kernel fitness path, the NSGA-II engine and the serving batcher,
     so none of them can drift apart.  ``aggregate_costs`` below is the
     scalar-objective view of this same definition.
+
+    It runs on the array module of its input: jax arrays and tracers are
+    reduced by ``jnp`` inside the caller's program, NumPy arrays by NumPy
+    on the host (the batcher's aggregation), with the same f32 adds in the
+    same order and the same maximum, so both give the same bits.
     """
+    xp = _xp(lat)
     total_lat = sum_layers(lat)
     total_en = sum_layers(en)
     if cfg.scenario == "LP":
         total_area = sum_layers(area)
         total_pw = sum_layers(pw)
     else:
-        total_area = jnp.max(area, axis=-1)
-        total_pw = jnp.max(pw, axis=-1)
+        total_area = xp.max(area, axis=-1)
+        total_pw = xp.max(pw, axis=-1)
     total_cons = total_area if cfg.constraint == "area" else total_pw
     return total_lat, total_en, total_area, total_pw, total_cons <= budget
 

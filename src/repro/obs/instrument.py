@@ -60,6 +60,10 @@ BATCHER_DISPATCHES = _metrics.counter(
 BATCHER_POINTS = _metrics.counter(
     "repro_batcher_points", "Per-layer points through the batcher",
     labels=("kind",))   # kind: submitted|unique|fresh
+BATCHER_AGGREGATED_ITEMS = _metrics.counter(
+    "repro_batcher_aggregated_items",
+    "Eval requests aggregated to their answers, by where",
+    labels=("path",))   # path: host (NumPy) | jit (blend fitness)
 BATCHER_FRESH_POINTS = _metrics.counter(
     "repro_batcher_fresh_points",
     "Fresh points handed to the cost evaluator, by layer type",
@@ -117,7 +121,7 @@ SPAN_NAMES = (
     "batcher.lookup",     # memo-cache get_many
     "batcher.eval",       # the fresh points' evaluation
     "batcher.fill",       # memo-cache put_many + per-point stack
-    "batcher.aggregate",  # per-item aggregation to fitness
+    "batcher.aggregate",  # the items' aggregation to their answers
     "xla.dispatch",       # one device program dispatch (program)
     "jit.trace",          # JAX traced a jaxpr (fun_name)
     "jit.lower",          # JAX lowered a jaxpr to MLIR (fun_name)

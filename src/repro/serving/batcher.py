@@ -14,12 +14,17 @@ funneled through one dispatcher thread that:
      evaluates only the genuinely new points in ONE fused call -- the Pallas
      per-row-layers kernel (``ops.batched_cost_multi``) when
      ``kernels.ops.on_tpu()``, the jitted jnp oracle elsewhere;
-  4. re-assembles each request's per-layer value tensor and aggregates it
-     with the exact jnp reductions of :func:`repro.core.env.genome_cost`.
+  4. aggregates every request on the host in one NumPy pass per group of
+     requests with the same layer count, through the same
+     :func:`repro.core.env.aggregate_costs_multi` that
+     :func:`repro.core.env.genome_cost` reduces with in the graph (a
+     ``blend`` request's scalar fitness keeps a jitted program: NumPy's
+     ``pow`` is not XLA's to the bit).
 
 Exactness: per-point cost values are bit-identical whatever batch they are
 computed in (the model is elementwise), and the final per-genome reduction
-runs over the same ``(b, N)`` shape the serial engine reduces over -- so a
+makes the same f32 adds in the same order as the serial engine's (per row,
+so rows of other requests beside it change nothing) -- so a
 search through the batcher returns bit-identical fitness to the same search
 run serially, cache hits and cross-request fusion included.  This is the
 property ``tests/test_search_service.py`` locks in.  (It holds on the jnp
@@ -57,9 +62,9 @@ ROW_WIDTH = NUM_FIELDS + 3   # layer fields + pe + kt + df
 @functools.lru_cache(maxsize=None)
 def _agg_fn(ecfg: "env_lib.EnvConfig"):
     """Jitted (b, N, 4) -> (b,) fitness: the SAME ``env.aggregate_costs``
-    reduction ``genome_cost``/``_decode_and_eval`` run, over the same
-    (b, N) shape, which is what keeps batched results bit-identical to
-    serial ones."""
+    reduction ``genome_cost``/``_decode_and_eval`` run.  Only a ``blend``
+    request's scalar fitness takes it (see :func:`_aggregate`); every
+    other request is aggregated on the host by :func:`_host_totals`."""
 
     @jax.jit
     def f(vals, budget):
@@ -71,21 +76,26 @@ def _agg_fn(ecfg: "env_lib.EnvConfig"):
     return f
 
 
-@functools.lru_cache(maxsize=None)
-def _agg_multi_fn(ecfg: "env_lib.EnvConfig"):
-    """Jitted (b, N, 4) -> (b, 4) aggregated (lat, en, area, pw): the SAME
-    ``env.aggregate_costs_multi`` reduction the NSGA-II in-graph fitness
-    runs, over the same (b, N) shape -- batched multi-objective results
-    stay bit-identical to serial ones."""
+def _host_totals(vals: np.ndarray, ecfg: "env_lib.EnvConfig", budget):
+    """(B, N, 4) f32 per-layer costs -> ``env.aggregate_costs_multi``'s
+    (total_lat, total_en, total_area, total_pw, feasible), each (B,),
+    reduced by NumPy.  ``budget`` is a scalar or one per row."""
+    cols = np.ascontiguousarray(np.moveaxis(vals, -1, 0))   # (4, B, N)
+    return env_lib.aggregate_costs_multi(cols[0], cols[1], cols[2],
+                                         cols[3], ecfg, budget)
 
-    @jax.jit
-    def f(vals, budget):
-        tl, te, ta, tp, _ = env_lib.aggregate_costs_multi(
-            vals[..., 0], vals[..., 1], vals[..., 2], vals[..., 3],
-            ecfg, budget)
-        return jnp.stack([tl, te, ta, tp], axis=-1)
 
-    return f
+def _host_result(totals, rows: slice, ecfg: "env_lib.EnvConfig",
+                 multi: bool) -> np.ndarray:
+    """One request's answer from its ``rows`` of :func:`_host_totals`:
+    (b, 4) (lat, en, area, pw) for ``evaluate_costs``, else (b,) fitness,
+    +inf where infeasible -- the bytes ``jnp.where(feas, perf, inf)``
+    gives."""
+    tl, te, ta, tp, feas = (t[rows] for t in totals)
+    if multi:
+        return np.stack([tl, te, ta, tp], axis=-1)
+    return np.where(feas, env_lib.select_objective(tl, te, ecfg),
+                    np.float32(np.inf))
 
 
 @jax.jit
@@ -131,6 +141,56 @@ class _Item:
         # right search even when one dispatch fuses N searches' requests.
         self.recorder = None
         self.t_enqueue = 0.0
+
+
+def _aggregate(items: List[_Item], per_point: np.ndarray) -> None:
+    """Set every item's ``fit`` from the dispatch's (P, 4) per-point costs
+    (the items' points, concatenated in order).
+
+    Items with the same layer count ``N``, scenario and constraint -- the
+    only fields of the EnvConfig that ``aggregate_costs_multi`` reads --
+    are reduced together in one NumPy pass over their rows, each row
+    against its own item's budget; each item then takes its objective
+    from its own rows.  Blend fitness, ``lat ** w * en ** (1 - w)``, goes
+    through :func:`_agg_fn` on the device: NumPy's ``pow`` and XLA's differ
+    in the last bit, while adds, maxima and comparisons are the same."""
+    groups: Dict[tuple, list] = {}
+    jit = []
+    off = 0
+    for it in items:
+        n = it.points.shape[0]
+        seg = (it, per_point[off:off + n])
+        off += n
+        if not it.multi and it.agg_key.objective == "blend":
+            jit.append(seg)
+        else:
+            ecfg = it.agg_key
+            groups.setdefault((it.shape[1], ecfg.scenario, ecfg.constraint),
+                              []).append(seg)
+    for (N, _, _), segs in groups.items():
+        if len(segs) == 1:
+            vals, budget = segs[0][1], segs[0][0].budget
+        else:
+            vals = np.concatenate([v for _, v in segs], axis=0)
+            budget = np.repeat(np.array([it.budget for it, _ in segs],
+                                        np.float32),
+                               [it.shape[0] for it, _ in segs])
+        totals = _host_totals(vals.reshape(-1, N, 4), segs[0][0].agg_key,
+                              budget)
+        row = 0
+        for it, _ in segs:
+            b = it.shape[0]
+            it.fit = _host_result(totals, slice(row, row + b), it.agg_key,
+                                  it.multi)
+            row += b
+    for it, v in jit:
+        it.fit = np.asarray(_agg_fn(it.agg_key)(
+            jnp.asarray(v.reshape(it.shape + (4,))), it.budget))
+    if len(items) > len(jit):
+        obs_instrument.BATCHER_AGGREGATED_ITEMS.inc(len(items) - len(jit),
+                                                    path="host")
+    if jit:
+        obs_instrument.BATCHER_AGGREGATED_ITEMS.inc(len(jit), path="jit")
 
 
 class CostEvalBatcher:
@@ -338,15 +398,16 @@ class CostEvalBatcher:
                     s["max_points_per_dispatch"], len(rows))
 
             with _phase("aggregate"):
-                off = 0
-                for it in items:
-                    n = it.points.shape[0]
-                    vals = per_point[off:off + n].reshape(it.shape + (4,))
-                    off += n
-                    agg = _agg_multi_fn(it.agg_key) if it.multi else _agg_fn(
-                        it.agg_key)
-                    it.fit = np.asarray(agg(jnp.asarray(vals), it.budget))
-                    it.event.set()
+                try:
+                    _aggregate(items, per_point)
+                finally:
+                    # Set the events only once every answer is computed:
+                    # each wakes a search thread that contends for the
+                    # interpreter lock.  On an error the answers already
+                    # made still go out; _loop hands it to the rest.
+                    for it in items:
+                        if it.fit is not None:
+                            it.event.set()
 
     def _record_dispatch(self, items: List[_Item], t0: float, dt: float,
                          t_eval: float, n_uniq: int, miss_index, inv,
@@ -487,23 +548,23 @@ def pack_point_rows(layers: np.ndarray, pe, kt, df) -> np.ndarray:
 
 def make_local_costs_eval(env, ecfg):
     """Serial nsga2's default fitness hook: ``eval_fn(pe, kt, df) -> (b, 4)``
-    running the EXACT per-point and aggregation programs a
-    :class:`CostEvalBatcher` dispatches -- minus the queue, fusion window
-    and memo cache.  Because ``eval_point_rows`` is bit-stable per row and
-    ``_agg_multi_fn`` is the same jitted program over the same (b, N, 4)
-    shape, a serial ``run_search`` and a service-batched one produce
-    byte-identical outcomes by construction (benchmarks/bench_frontier.py
-    asserts it end to end).
+    running the EXACT per-point program and host aggregation a
+    :class:`CostEvalBatcher` dispatch runs -- minus the queue, fusion
+    window and memo cache.  Because ``eval_point_rows`` is bit-stable per
+    row and ``_host_totals`` reduces each row alone, a serial
+    ``run_search`` and a service-batched one produce byte-identical
+    outcomes by construction (benchmarks/bench_frontier.py asserts it end
+    to end).
     """
     layers = np.asarray(env.layers, np.float32)
     budget = np.float32(env.budget)
-    agg = _agg_multi_fn(ecfg)
 
     def eval_fn(pe, kt, df):
         pe = np.asarray(pe, np.float32)
         b, N = pe.shape
         rows = pack_point_rows(layers, pe, kt, df)
         vals = eval_point_rows(rows).reshape(b, N, 4)
-        return np.asarray(agg(jnp.asarray(vals), budget))
+        return _host_result(_host_totals(vals, ecfg, budget), slice(None),
+                            ecfg, multi=True)
 
     return eval_fn

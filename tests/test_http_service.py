@@ -256,6 +256,14 @@ def test_http_metrics_accrue_when_telemetry_enabled():
                                                     code="200")
             client.stats()
             client.stats()
+            # The handler counts a request after its response is written,
+            # so the client can read the counter before the server thread
+            # has counted the second call: wait for it, then compare.
+            deadline = time.monotonic() + 5.0
+            while (instrument.HTTP_REQUESTS.value(route="/v1/stats",
+                                                  code="200") < before + 2
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
             assert instrument.HTTP_REQUESTS.value(
                 route="/v1/stats", code="200") == before + 2
             text = client.metrics_text()
